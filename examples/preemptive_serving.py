@@ -12,21 +12,18 @@ With ``--n-devices N`` (N > 1) the same two workloads run on a
 ClusterExecutor: inference pinned to device 0, training pinned to device
 N-1 (the boundary device — the admission path the cross-device analysis
 guards), so the inference WCRT is computed on the multi-device platform
-and the per-device MORTs show the isolation.
+and the per-device MORTs show the isolation.  It exits with an error when
+fewer than N JAX devices are visible, and when either job fails.
 """
 import argparse
-import time
 
-import jax
 import jax.numpy as jnp
 
 from repro.configs import get
-from repro.core.segments import SegmentedWorkload, SlicedOp
+from repro.launch.fleet import decode_workload, train_workload
+from repro.launch.preemptive import check_preemptive, run_preemptive
 from repro.launch.serve import InferenceEngine
-from repro.launch.steps import build_train_step
-from repro.models import transformer
-from repro.optim import adamw
-from repro.sched import JobProfile, RTJob, connect
+from repro.runtime import enable_compile_cache, visible_devices
 
 
 def main() -> None:
@@ -34,125 +31,40 @@ def main() -> None:
     ap.add_argument("--n-devices", type=int, default=1,
                     help="N>1: train on device N-1, infer on device 0")
     args = ap.parse_args()
+    enable_compile_cache()
     n_devices = args.n_devices
     infer_dev, train_dev = 0, n_devices - 1
-    # physical placement: pin each workload's arrays (and therefore its
-    # XLA programs) to its scheduling device when the host exposes that
-    # many jax devices; otherwise the scheduling isolation still holds
-    # but the programs share one physical device (warn — the analysis
-    # models N devices)
-    jdevs = jax.devices()
-    if n_devices > 1 and len(jdevs) < n_devices:
-        print(f"WARNING: --n-devices {n_devices} but only {len(jdevs)} "
-              f"jax device(s); programs share one physical device "
-              f"(set XLA_FLAGS=--xla_force_host_platform_device_count="
-              f"{n_devices})")
-    infer_jdev = jdevs[infer_dev] if len(jdevs) > infer_dev else None
-    train_jdev = jdevs[train_dev] if len(jdevs) > train_dev else None
-    # --- workloads -----------------------------------------------------
-    infer_cfg = get("smollm-135m").reduced()
-    train_cfg = get("olmo-1b").reduced()
-    engine = InferenceEngine(infer_cfg, max_len=64, device=infer_jdev)
-    params = transformer.init_params(train_cfg, jax.random.PRNGKey(0))
-    if train_jdev is not None:
-        params = jax.device_put(params, train_jdev)
-    state = {"params": params, "opt": adamw.init_opt_state(params)}
-    step_fn = jax.jit(build_train_step(train_cfg))
-    microbatches = [
-        {"inputs": jnp.zeros((1, 32), jnp.int32),
-         "labels": jnp.zeros((1, 32), jnp.int32)} for _ in range(2)]
-    if train_jdev is not None:
-        microbatches = jax.device_put(microbatches, train_jdev)
-
-    # --- the job bodies as segmented workloads ---------------------------
+    # physical placement: each workload's arrays (and therefore its XLA
+    # programs) live on its scheduling device
+    jdevs = visible_devices(n_devices)
     # inference: one prefill slice + 4 decode-token slices per release
-    prompt = jnp.zeros((2, 8), jnp.int32)
-    infer_wl = (SegmentedWorkload("infer")
-                .device(lambda: engine.prefill_segment(prompt))
-                .device(lambda: engine.decode_segment(4)))
+    engine = InferenceEngine(get("smollm-135m").reduced(), max_len=64,
+                             device=jdevs[infer_dev])
+    infer_wl = decode_workload("infer", engine,
+                               jnp.zeros((2, 8), jnp.int32), 4, 1)
+    # training: one full train step per slice, two microbatches
+    train_wl = train_workload("train", get("olmo-1b").reduced(), batch=1,
+                              seq_len=32, microbatches=2,
+                              jdev=jdevs[train_dev])
 
-    def train_op() -> SlicedOp:
-        """One best-effort training release: each slice is a full train
-        step on one microbatch (the bounded-duration dispatch that keeps
-        the device preemptible), state committed at finalize."""
-        def step(carry, i):
-            p, o, _ = step_fn(carry[0], carry[1], microbatches[i])
-            return (p, o)
-
-        def finalize(carry):
-            state.update(params=carry[0], opt=carry[1])
-            return carry[1]
-
-        return SlicedOp(len(microbatches),
-                        lambda: (state["params"], state["opt"]),
-                        step, finalize, label="train_step")
-
-    train_wl = SegmentedWorkload("train").device(train_op)
-
-    # --- measured slice profiles -> admission control --------------------
-    # (the first profile rep doubles as the jit warm-up)
-    infer_prof = infer_wl.profile(reps=2)
-    train_prof = train_wl.profile(reps=2)
-    # epsilon = admission-update cost + the residual of one in-flight
-    # *slice* (any job's): preemption takes effect at slice boundaries,
-    # so the bound is one slice — not the whole train step the pre-sliced
-    # API had to assume (DESIGN.md §6)
-    max_slice = max(infer_prof.max_slice_ms, train_prof.max_slice_ms)
-    eps_ms = 1.0 + max_slice * 1.2
-
-    # --- the cluster: admit→place→bind, then run preemptively ------------
-    # (through the unified facade: connect() owns an in-process cluster;
-    # the same submit() would reach a daemon given a socket path)
-    client = connect(n_devices=n_devices, policy="notify",
-                     wait_mode="suspend", n_cpus=1, epsilon_ms=eps_ms)
-    cluster = client.cluster
-    res = client.submit(
-        JobProfile.from_workload(infer_prof, period_ms=1500, priority=50,
-                                 margin=2.0, device=infer_dev),
-        workload=infer_wl, n_iterations=100)
-    print(f"inference admitted={res['admitted']} on device "
-          f"{res['device']} WCRT={res['wcrt'].get('infer', 0):.1f}ms "
-          f"(slices {[round(s, 1) for s in infer_prof.device[1].slice_ms]}"
-          f"ms, max slice {max_slice:.1f}ms, epsilon {eps_ms:.0f}ms)")
-    res_train = client.submit(
-        JobProfile.from_workload(train_prof, period_ms=500, priority=0,
-                                 best_effort=True, margin=1.5,
-                                 device=train_dev),
-        workload=train_wl, n_iterations=100)
-    if res["job"] is None or res_train["job"] is None:
-        # report the refusal instead of crashing on job=None — nothing
-        # has started yet (submit was called without start=True)
-        client.close(shutdown=True)
-        refused = res if res["job"] is None else res_train
-        why = refused.get("error") or refused["wcrt"]
-        raise SystemExit(f"admission refused: {why}")
-    infer: RTJob = res["job"]
-    train: RTJob = res_train["job"]
-    train.start(cluster, stop_after_s=6.0)
-    time.sleep(0.05)
-    infer.start(cluster, stop_after_s=6.0)
-    infer.join(30)
-    train.join(30)
-    client.close(shutdown=True)
-    cluster.assert_migration_free()
-
-    wcrt = res["wcrt"].get("infer", float("inf"))
-    mort_ms = (infer.stats.mort or 0.0) * 1e3
-    obs_slice = (max(infer.stats.max_slice_time or 0.0,
-                     train.stats.max_slice_time or 0.0)) * 1e3
-    print(f"inference: {infer.stats.completions} jobs, "
-          f"MORT {mort_ms:.1f}ms vs WCRT {wcrt:.1f}ms, "
-          f"misses {infer.stats.deadline_misses}")
-    print(f"training (best-effort): {train.stats.completions} releases "
-          f"alongside; longest observed slice {obs_slice:.1f}ms "
-          f"(protective bound {eps_ms:.0f}ms)")
+    result = run_preemptive(infer_wl, train_wl, n_devices=n_devices,
+                            infer_device=infer_dev, train_device=train_dev,
+                            policy="notify")
+    print(f"inference: {result['rt_completions']} jobs, "
+          f"MORT {result['rt_mort_ms']:.1f}ms vs WCRT "
+          f"{result['wcrt_ms']:.1f}ms, "
+          f"misses {result['rt_deadline_misses']} (slices "
+          f"{[round(s, 1) for s in result['rt_slice_ms']]}ms)")
+    print(f"training (best-effort): {result['be_completions']} releases "
+          f"alongside; longest observed slice "
+          f"{result['max_slice_ms']:.1f}ms (protective bound "
+          f"{result['epsilon_ms']:.0f}ms)")
     if n_devices > 1:
         morts = {d: (round(v * 1e3, 1) if v is not None else None)
-                 for d, v in client.per_device_mort().items()}
+                 for d, v in result["per_device_mort"].items()}
         print(f"per-device MORT (ms): {morts} "
               f"(infer on {infer_dev}, train on {train_dev})")
-    assert infer.stats.completions > 0, "inference never completed"
-    assert mort_ms <= wcrt + 1e-6, "WCRT bound violated!"
+    check_preemptive(result)
     print("preemptive_serving OK")
 
 

@@ -8,7 +8,9 @@ exploited natively: the score matmul is (G q-heads x D) @ (D x bk) per KV
 head — q heads grouped by their kv head, so the cache is read once.
 
 Invalid tail entries (cache_len <= idx) and sliding windows are masked via
-the per-batch length vector (SMEM-style scalar input).
+the per-batch length vector, scalar-prefetched into SMEM (a rank-1 VMEM
+block of one length per batch element is refused by the TPU compiler once
+the batch exceeds 1).
 """
 from __future__ import annotations
 
@@ -24,17 +26,28 @@ from ..core.segments import SlicedOp, n_slices_for
 
 NEG_INF = -1e30
 
+#: default KV block of one grid step; caches longer than one block must be
+#: a whole number of blocks (``cache_len_for``)
+BLOCK_K = 512
+
+
+def cache_len_for(n: int, block_k: int = BLOCK_K) -> int:
+    """The least cache length >= ``n`` that ``flash_decode``'s blocks
+    tile: ``n`` itself up to one block, else a multiple of the block."""
+    return n if n <= block_k else -(-n // block_k) * block_k
+
 
 def _block_update(len_ref, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, *,
                   scale: float, window: Optional[int], cols_base,
                   block_k: int, n_heads: int, n_kv_heads: int):
     """One online-softmax KV-block merge on the VMEM carry — shared by the
-    whole-grid kernel and the sliced (resumable) kernel."""
+    whole-grid kernel and the sliced (resumable) kernel.  ``len_ref`` is
+    the whole (B,) length vector in SMEM, indexed by the batch grid axis."""
     g = n_heads // n_kv_heads
     q = q_ref[0].astype(jnp.float32)              # (H, D)
     k = k_ref[0].astype(jnp.float32)              # (bk, Hkv, D)
     v = v_ref[0].astype(jnp.float32)
-    cache_len = len_ref[0]
+    cache_len = len_ref[pl.program_id(0)]
 
     cols = cols_base + jax.lax.iota(jnp.int32, block_k)
     valid = cols < cache_len
@@ -97,8 +110,8 @@ def _carry_kernel(len_ref, q_ref, k_ref, v_ref, m0_ref, l0_ref, acc0_ref,
 
     @pl.when(ki == 0)
     def _init():
-        m_scr[...] = m0_ref[0]
-        l_scr[...] = l0_ref[0]
+        m_scr[...] = m0_ref[0, 0]
+        l_scr[...] = l0_ref[0, 0]
         acc_scr[...] = acc0_ref[0]
 
     _block_update(len_ref, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
@@ -108,13 +121,20 @@ def _carry_kernel(len_ref, q_ref, k_ref, v_ref, m0_ref, l0_ref, acc0_ref,
 
     @pl.when(ki == n_kv - 1)
     def _flush():
-        m_ref[0] = m_scr[...]
-        l_ref[0] = l_scr[...]
+        m_ref[0, 0] = m_scr[...]
+        l_ref[0, 0] = l_scr[...]
         acc_ref[0] = acc_scr[...]
 
 
+def _scratch(h: int, d: int):
+    """VMEM merge state of one batch element: running max, sum, acc."""
+    return [pltpu.VMEM((h,), jnp.float32),
+            pltpu.VMEM((h,), jnp.float32),
+            pltpu.VMEM((h, d), jnp.float32)]
+
+
 def flash_decode(q, k_cache, v_cache, cache_len, *,
-                 window: Optional[int] = None, block_k: int = 512,
+                 window: Optional[int] = None, block_k: int = BLOCK_K,
                  interpret: bool = False):
     """q: (B, H, D); caches: (B, Smax, Hkv, D); cache_len: (B,) or scalar.
     Returns (B, H, D)."""
@@ -129,28 +149,26 @@ def flash_decode(q, k_cache, v_cache, cache_len, *,
         n_kv=n_kv, n_heads=h, n_kv_heads=hkv)
     return pl.pallas_call(
         kernel,
-        grid=(b, n_kv),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b_, k_: (b_,)),
-            pl.BlockSpec((1, h, d), lambda b_, k_: (b_, 0, 0)),
-            pl.BlockSpec((1, block_k, hkv, d), lambda b_, k_:
-                         (b_, k_, 0, 0)),
-            pl.BlockSpec((1, block_k, hkv, d), lambda b_, k_:
-                         (b_, k_, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, h, d), lambda b_, k_: (b_, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, n_kv),
+            in_specs=[
+                pl.BlockSpec((1, h, d), lambda b_, k_, _: (b_, 0, 0)),
+                pl.BlockSpec((1, block_k, hkv, d), lambda b_, k_, _:
+                             (b_, k_, 0, 0)),
+                pl.BlockSpec((1, block_k, hkv, d), lambda b_, k_, _:
+                             (b_, k_, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, h, d), lambda b_, k_, _: (b_, 0, 0)),
+            scratch_shapes=_scratch(h, d),
+        ),
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((h,), jnp.float32),
-            pltpu.VMEM((h,), jnp.float32),
-            pltpu.VMEM((h, d), jnp.float32),
-        ],
         interpret=interpret,
     )(lens, q, k_cache, v_cache)
 
 
 def flash_decode_sliced(q, k_cache, v_cache, cache_len, *,
-                        window: Optional[int] = None, block_k: int = 512,
+                        window: Optional[int] = None, block_k: int = BLOCK_K,
                         kv_slice: int = 1,
                         interpret: bool = False) -> SlicedOp:
     """Sliced, resumable flash decode: each slice merges ``kv_slice``
@@ -182,33 +200,34 @@ def flash_decode_sliced(q, k_cache, v_cache, cache_len, *,
             _carry_kernel, scale=scale, window=window,
             kv_offset=k0 * block_k, block_k=block_k, n_kv=nk,
             n_heads=h, n_kv_heads=hkv)
-        carry_spec_1d = pl.BlockSpec((1, h), lambda b_, k_: (b_, 0))
-        carry_spec_2d = pl.BlockSpec((1, h, d), lambda b_, k_: (b_, 0, 0))
-        return pl.pallas_call(
+        # (B, H) stats travel as (B, 1, H): a (1, H) block of a (B, H)
+        # array breaks the TPU's 8-row tiling rule once B > 1
+        carry_spec_1d = pl.BlockSpec((1, 1, h), lambda b_, k_, _: (b_, 0, 0))
+        carry_spec_2d = pl.BlockSpec((1, h, d), lambda b_, k_, _: (b_, 0, 0))
+        m, lsum, acc = pl.pallas_call(
             kernel,
-            grid=(b, nk),
-            in_specs=[
-                pl.BlockSpec((1,), lambda b_, k_: (b_,)),
-                pl.BlockSpec((1, h, d), lambda b_, k_: (b_, 0, 0)),
-                pl.BlockSpec((1, block_k, hkv, d), lambda b_, k_:
-                             (b_, k_, 0, 0)),
-                pl.BlockSpec((1, block_k, hkv, d), lambda b_, k_:
-                             (b_, k_, 0, 0)),
-                carry_spec_1d, carry_spec_1d, carry_spec_2d,
-            ],
-            out_specs=[carry_spec_1d, carry_spec_1d, carry_spec_2d],
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(b, nk),
+                in_specs=[
+                    pl.BlockSpec((1, h, d), lambda b_, k_, _: (b_, 0, 0)),
+                    pl.BlockSpec((1, block_k, hkv, d), lambda b_, k_, _:
+                                 (b_, k_, 0, 0)),
+                    pl.BlockSpec((1, block_k, hkv, d), lambda b_, k_, _:
+                                 (b_, k_, 0, 0)),
+                    carry_spec_1d, carry_spec_1d, carry_spec_2d,
+                ],
+                out_specs=[carry_spec_1d, carry_spec_1d, carry_spec_2d],
+                scratch_shapes=_scratch(h, d),
+            ),
             out_shape=[
-                jax.ShapeDtypeStruct((b, h), jnp.float32),
-                jax.ShapeDtypeStruct((b, h), jnp.float32),
+                jax.ShapeDtypeStruct((b, 1, h), jnp.float32),
+                jax.ShapeDtypeStruct((b, 1, h), jnp.float32),
                 jax.ShapeDtypeStruct((b, h, d), jnp.float32),
             ],
-            scratch_shapes=[
-                pltpu.VMEM((h,), jnp.float32),
-                pltpu.VMEM((h,), jnp.float32),
-                pltpu.VMEM((h, d), jnp.float32),
-            ],
             interpret=interpret,
-        )(lens, q, ks, vs, m0, l0, acc0)
+        )(lens, q, ks, vs, m0[:, None], l0[:, None], acc0)
+        return m[:, 0], lsum[:, 0], acc
 
     def finalize(carry):
         _, lsum, acc = carry

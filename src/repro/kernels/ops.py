@@ -9,7 +9,9 @@ The global default is platform-derived: Pallas on TPU, reference elsewhere.
 ``set_use_pallas`` overrides it (tests use interpret-mode Pallas on CPU);
 the ``REPRO_PALLAS`` environment variable provides the same override for
 subprocesses (``interpret`` → interpret-mode Pallas, as in CI's
-runtime-smoke job; ``on``/``off`` → force the dispatch).
+runtime-smoke job; ``on``/``off`` → force the dispatch).  ``interpret``
+and ``off`` are CPU-test switches: on a TPU backend they would hide the
+compiled kernels, so there they raise instead of taking effect.
 
 The ``*_sliced`` entry points return a :class:`repro.core.segments.
 SlicedOp` — the op split into K-grid-step dispatches with an explicit
@@ -19,46 +21,61 @@ preemption delay, DESIGN.md §6) and checkpoint mid-op.
 from __future__ import annotations
 
 import os
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 
 from ..core.segments import SlicedOp
 from . import ref
 
-_USE_PALLAS: Optional[bool] = None  # None -> auto (TPU only)
-_INTERPRET = False                  # run Pallas kernels in interpret mode
+#: (use_pallas, interpret) set by ``set_use_pallas``; None -> environment
+_OVERRIDE: Optional[Tuple[Optional[bool], bool]] = None
 
-_ENV = os.environ.get("REPRO_PALLAS", "").lower()
-if _ENV == "interpret":             # CI runtime-smoke: exercise the Pallas
-    _USE_PALLAS, _INTERPRET = True, True   # path on CPU runners
-elif _ENV in ("on", "1", "true"):
-    _USE_PALLAS = True
-elif _ENV in ("off", "0", "false"):
-    _USE_PALLAS = False
+#: REPRO_PALLAS value -> (use_pallas, interpret); None -> platform default
+_ENV_DISPATCH = {"": (None, False), "interpret": (True, True),
+                 "on": (True, False), "1": (True, False),
+                 "true": (True, False), "off": (False, False),
+                 "0": (False, False), "false": (False, False)}
 
 
 def set_use_pallas(value: Optional[bool], interpret: bool = False) -> None:
-    global _USE_PALLAS, _INTERPRET
-    _USE_PALLAS = value
-    _INTERPRET = interpret
+    """Override the dispatch for this process (None restores the
+    environment / platform default)."""
+    global _OVERRIDE
+    _OVERRIDE = None if value is None else (value, interpret)
+
+
+def _dispatch() -> Tuple[Optional[bool], bool]:
+    if _OVERRIDE is not None:
+        return _OVERRIDE
+    env = os.environ.get("REPRO_PALLAS", "").lower()
+    if env not in _ENV_DISPATCH:
+        raise ValueError(f"REPRO_PALLAS={env!r}: expected one of "
+                         f"{sorted(k for k in _ENV_DISPATCH if k)}")
+    value, interpret = _ENV_DISPATCH[env]
+    if (value is False or interpret) and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"REPRO_PALLAS={env} would keep the compiled Pallas kernels "
+            f"off the TPU; it is a CPU-test switch — unset it")
+    return value, interpret
 
 
 def use_pallas() -> bool:
-    if _USE_PALLAS is not None:
-        return _USE_PALLAS
+    value = _dispatch()[0]
+    if value is not None:
+        return value
     return jax.default_backend() == "tpu"
 
 
 def interpret_mode() -> bool:
-    return _INTERPRET
+    return _dispatch()[1]
 
 
 def _sliced_interpret() -> bool:
     """Sliced execution always goes through the Pallas kernels (the carry
     contract is kernel-level); off-TPU they run in interpret mode."""
     if use_pallas():
-        return _INTERPRET
+        return interpret_mode()
     return jax.default_backend() != "tpu"
 
 
@@ -72,7 +89,7 @@ def attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
     if use_pallas():
         from .flash_attention import flash_attention
         return flash_attention(q, k, v, causal=causal, window=window,
-                               q_offset=q_offset, interpret=_INTERPRET)
+                               q_offset=q_offset, interpret=interpret_mode())
     return ref.attention(q, k, v, causal=causal, window=window,
                          q_offset=q_offset, chunk=chunk)
 
@@ -83,7 +100,7 @@ def decode_attention(q, k_cache, v_cache, cache_len, *,
     if use_pallas():
         from .decode_attention import flash_decode
         return flash_decode(q, k_cache, v_cache, cache_len, window=window,
-                            interpret=_INTERPRET)
+                            interpret=interpret_mode())
     return ref.decode_attention(q, k_cache, v_cache, cache_len,
                                 window=window)
 
@@ -96,14 +113,15 @@ def mamba_scan(x, dt, A, B, C, D, h0=None):
     if use_pallas():
         from .mamba_scan import mamba_scan_pallas
         return mamba_scan_pallas(x, dt, A, B, C, D, h0=h0,
-                                 interpret=_INTERPRET)
+                                 interpret=interpret_mode())
     return ref.mamba_scan(x, dt, A, B, C, D, h0=h0)
 
 
 def rwkv6_scan(r, k, v, w, u, s0=None):
     if use_pallas():
         from .rwkv6 import rwkv6_scan_pallas
-        return rwkv6_scan_pallas(r, k, v, w, u, s0=s0, interpret=_INTERPRET)
+        return rwkv6_scan_pallas(r, k, v, w, u, s0=s0,
+                                 interpret=interpret_mode())
     return ref.rwkv6_scan(r, k, v, w, u, s0=s0)
 
 
@@ -154,7 +172,7 @@ def mamba_scan_sliced(x, dt, A, B, C, D, h0=None, *, chunk: int = 32,
     if use_pallas():
         return _sliced(x, dt, A, B, C, D, h0=h0, chunk=chunk,
                        block_d=block_d, slice_chunks=slice_chunks,
-                       interpret=_INTERPRET)
+                       interpret=interpret_mode())
     return _sliced(x, dt, A, B, C, D, h0=h0, chunk=chunk, block_d=block_d,
                    slice_chunks=slice_chunks,
                    scan_fn=lambda xw, dtw, A_, Bw, Cw, D_, h:
@@ -168,7 +186,7 @@ def rwkv6_scan_sliced(r, k, v, w, u, s0=None, *, chunk: int = 32,
     from .rwkv6 import rwkv6_scan_sliced as _sliced
     if use_pallas():
         return _sliced(r, k, v, w, u, s0=s0, chunk=chunk,
-                       slice_chunks=slice_chunks, interpret=_INTERPRET)
+                       slice_chunks=slice_chunks, interpret=interpret_mode())
     return _sliced(r, k, v, w, u, s0=s0, chunk=chunk,
                    slice_chunks=slice_chunks,
                    scan_fn=lambda rw, kw, vw, ww, u_, st:

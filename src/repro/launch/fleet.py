@@ -36,6 +36,7 @@ from ..configs import get
 from ..core.segments import SegmentedWorkload, SlicedOp
 from ..models import transformer
 from ..optim import adamw
+from ..runtime import enable_compile_cache, visible_devices
 from .serve import InferenceEngine
 from .steps import build_train_step
 
@@ -117,6 +118,46 @@ def default_fleet(n_devices: int = 2,
 # member -> SegmentedWorkload (the measured pipeline's entry)
 # --------------------------------------------------------------------------
 
+def decode_workload(name: str, eng: InferenceEngine, prompt,
+                    decode_tokens: int, slice_tokens: int
+                    ) -> SegmentedWorkload:
+    """RT serving: a one-slice prefill segment, then ``decode_tokens``
+    greedy tokens as a sliced decode segment of ``slice_tokens``."""
+    return (SegmentedWorkload(name)
+            .device(lambda: eng.prefill_segment(prompt), label="prefill")
+            .device(lambda: eng.decode_segment(
+                decode_tokens, slice_tokens=slice_tokens), label="decode"))
+
+
+def train_workload(name: str, cfg, *, batch: int, seq_len: int,
+                   microbatches: int, jdev=None,
+                   seed: int = 0) -> SegmentedWorkload:
+    """Best-effort training: one optimizer step per slice, one
+    microbatch each, the (params, opt) state committed at finalize."""
+    params = transformer.init_params(cfg, jax.random.PRNGKey(seed))
+    mbs = [{"inputs": jnp.zeros((batch, seq_len), jnp.int32),
+            "labels": jnp.zeros((batch, seq_len), jnp.int32)}
+           for _ in range(microbatches)]
+    if jdev is not None:
+        params, mbs = jax.device_put((params, mbs), jdev)
+    state = {"params": params, "opt": adamw.init_opt_state(params)}
+    step_fn = jax.jit(build_train_step(cfg))
+
+    def train_op() -> SlicedOp:
+        def step(carry, i):
+            p, o, _ = step_fn(carry[0], carry[1], mbs[i])
+            return (p, o)
+
+        def finalize(carry):
+            state.update(params=carry[0], opt=carry[1])
+            return None
+
+        return SlicedOp(len(mbs), lambda: (state["params"], state["opt"]),
+                        step, finalize, label="train_step")
+
+    return SegmentedWorkload(name).device(train_op, label="train")
+
+
 def build_member_workload(member: FleetMember, jdev=None,
                           seed: int = 0) -> SegmentedWorkload:
     """The member's device work as a ``SegmentedWorkload`` — profiled
@@ -126,47 +167,25 @@ def build_member_workload(member: FleetMember, jdev=None,
     cfg = get(member.arch).reduced()
     if member.role == "decode":
         eng = InferenceEngine(
-            cfg, max_len=member.prompt_len + member.decode_tokens + 8,
+            cfg, max_len=member.prompt_len + member.decode_tokens,
             seed=seed, device=jdev)
         prompt = jnp.zeros((member.batch, member.prompt_len), jnp.int32)
-        return (SegmentedWorkload(member.name)
-                .device(lambda: eng.prefill_segment(prompt),
-                        label="prefill")
-                .device(lambda: eng.decode_segment(
-                    member.decode_tokens,
-                    slice_tokens=member.slice_tokens), label="decode"))
+        return decode_workload(member.name, eng, prompt,
+                               member.decode_tokens, member.slice_tokens)
+    if member.role == "train":
+        return train_workload(member.name, cfg, batch=member.batch,
+                              seq_len=member.seq_len,
+                              microbatches=member.microbatches, jdev=jdev,
+                              seed=seed)
 
+    # eval: forward-only lm_loss, one microbatch per slice
     params = transformer.init_params(cfg, jax.random.PRNGKey(seed))
-    if jdev is not None:
-        params = jax.device_put(params, jdev)
     shape = (member.batch, member.seq_len)
     mbs = [{"inputs": jnp.zeros(shape, jnp.int32),
             "labels": jnp.zeros(shape, jnp.int32)}
            for _ in range(member.microbatches)]
     if jdev is not None:
-        mbs = jax.device_put(mbs, jdev)
-
-    if member.role == "train":
-        state = {"params": params, "opt": adamw.init_opt_state(params)}
-        step_fn = jax.jit(build_train_step(cfg))
-
-        def train_op() -> SlicedOp:
-            def step(carry, i):
-                p, o, _ = step_fn(carry[0], carry[1], mbs[i])
-                return (p, o)
-
-            def finalize(carry):
-                state.update(params=carry[0], opt=carry[1])
-                return None
-
-            return SlicedOp(len(mbs),
-                            lambda: (state["params"], state["opt"]),
-                            step, finalize, label="train_step")
-
-        return SegmentedWorkload(member.name).device(train_op,
-                                                     label="train")
-
-    # eval: forward-only lm_loss, one microbatch per slice
+        params, mbs = jax.device_put((params, mbs), jdev)
     loss_fn = jax.jit(lambda p, b: transformer.lm_loss(cfg, p, b))
 
     def eval_op() -> SlicedOp:
@@ -180,16 +199,18 @@ def build_member_workload(member: FleetMember, jdev=None,
 
 def member_op_factory(member: FleetMember, seed: int = 1):
     """A durable-workload factory for one member: builds the member's
-    stack lazily on first use, then returns a fresh ``SlicedOp`` per
-    release (for decode members the prefill runs inline, mirroring
+    stack lazily on first use, on the JAX device of the cluster device
+    the job is bound to, then returns a fresh ``SlicedOp`` per release
+    (for decode members the prefill runs inline, mirroring
     ``serve.register_serving_workloads``)."""
-    built: dict = {}
+    built: Dict[int, SegmentedWorkload] = {}
 
-    def factory() -> SlicedOp:
-        wl = built.get("wl")
+    def factory(device: int = 0) -> SlicedOp:
+        wl = built.get(device)
         if wl is None:
-            wl = build_member_workload(member, seed=seed)
-            built["wl"] = wl
+            wl = build_member_workload(
+                member, jdev=visible_devices(device + 1)[device], seed=seed)
+            built[device] = wl
         if member.role == "decode":
             # entries: [prefill, decode] — run prefill to completion
             # inline, hand the executor the resumable decode segment
@@ -229,19 +250,13 @@ def launch_fleet(members: Sequence[FleetMember], *, n_devices: int = 2,
     from ..sched import JobProfile, connect
 
     log = print if verbose else (lambda *a, **k: None)
-    jdevs = jax.devices()
-    if n_devices > 1 and len(jdevs) < n_devices:
-        log(f"WARNING: --n-devices {n_devices} but only {len(jdevs)} jax "
-            f"device(s); programs share one physical device (set "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count="
-            f"{n_devices})")
+    jdevs = visible_devices(n_devices)
 
     workloads: Dict[str, SegmentedWorkload] = {}
     profiles: Dict[str, object] = {}
     for m in members:
-        jdev = jdevs[m.device] if len(jdevs) > m.device else None
         t0 = time.perf_counter()
-        workloads[m.name] = build_member_workload(m, jdev=jdev)
+        workloads[m.name] = build_member_workload(m, jdev=jdevs[m.device])
         # the first profile rep doubles as the jit warm-up
         profiles[m.name] = workloads[m.name].profile(reps=reps)
         log(f"profiled {m.name} ({m.arch}, {m.role}, tier {m.tier}): "
@@ -296,6 +311,8 @@ def launch_fleet(members: Sequence[FleetMember], *, n_devices: int = 2,
                 job.start(cluster, stop_after_s=duration_s)
         client.join(duration_s * 10 + 120)
 
+        report["errors"] = {m.name: repr(job.error) for m, job in jobs
+                            if job.error is not None}
         report["per_model"] = cluster.per_model_stats()
         report["per_tier"] = cluster.per_tier_stats()
         report["per_device_mort"] = client.per_device_mort()
@@ -307,8 +324,10 @@ def launch_fleet(members: Sequence[FleetMember], *, n_devices: int = 2,
 
 
 def check_fleet_report(report: dict) -> None:
-    """The fleet acceptance assertions: every admitted RT model
-    completed releases and observed MORT within its admitted WCRT."""
+    """The fleet acceptance assertions: no member's job failed, and
+    every admitted RT model completed releases and observed MORT within
+    its admitted WCRT."""
+    assert not report["errors"], f"fleet job(s) failed: {report['errors']}"
     for name, m in report["models"].items():
         if m["best_effort"] or not m["admitted"]:
             continue
@@ -351,6 +370,7 @@ def run_fleet_daemon(members: Sequence[FleetMember], args) -> None:
 
     from ..sched.daemon import SchedDaemon
 
+    visible_devices(args.n_devices)
     register_fleet_workloads(members)
     daemon = SchedDaemon(args.store, args.socket,
                          n_devices=args.n_devices,
@@ -416,6 +436,7 @@ def main() -> None:
 
     models = args.models.split(",") if args.models else None
     members = default_fleet(args.n_devices, models)
+    enable_compile_cache()
     if args.daemon:
         if not args.store:
             ap.error("--daemon requires --store")

@@ -32,7 +32,9 @@ import numpy as np
 
 from ..configs import get
 from ..core.segments import SlicedOp, n_slices_for
+from ..kernels.decode_attention import cache_len_for
 from ..models import transformer
+from ..runtime import enable_compile_cache, visible_devices
 
 
 class InferenceEngine:
@@ -40,14 +42,16 @@ class InferenceEngine:
                  device=None):
         """``device`` (a ``jax.Device``) places the params — and therefore
         every jitted program — on one accelerator of a multi-device host;
-        None keeps the platform default."""
+        None keeps the platform default.  ``max_len`` is rounded up to a
+        length the decode kernel's cache blocks tile (the kernel masks
+        past each row's length), so any prompt + output length fits."""
         self.cfg = cfg
         self.device = device
         self.params = params if params is not None else \
             transformer.init_params(cfg, jax.random.PRNGKey(seed))
         if device is not None:
             self.params = jax.device_put(self.params, device)
-        self.max_len = max_len
+        self.max_len = max_len = cache_len_for(max_len)
         self._prefill = jax.jit(
             lambda p, t: transformer.prefill(cfg, p, t, max_len))
         self._decode = jax.jit(
@@ -56,6 +60,7 @@ class InferenceEngine:
         self.cache = None
         self.pos = None
         self.last_tok = None
+        self.last_out = None    # (B, n) tokens of the last finished decode
 
     def prefill_batch(self, tokens: jax.Array):
         """tokens: (B, S).  Returns last-token logits."""
@@ -114,6 +119,7 @@ class InferenceEngine:
             self.cache = carry["cache"]
             self.pos = carry["pos"]
             self.last_tok = carry["tok"]
+            self.last_out = carry["out"]
             return carry["out"]
 
         return SlicedOp(n_slices_for(n, slice_tokens), init, step,
@@ -124,31 +130,59 @@ class InferenceEngine:
         segment to completion.  Returns (B, n) tokens."""
         return self.decode_segment(n).run()
 
+    def teacher_forced_logits(self, tokens: jax.Array, inputs: jax.Array):
+        """Prefill ``tokens`` (B, S), then feed ``inputs[:, j]`` at decode
+        step j instead of the engine's own choice — how a served
+        generation is re-scored by another kernel path.  Returns the
+        prefill's last-position logits (B, V) and every step's logits
+        (n, B, V)."""
+        first = self.prefill_batch(tokens)
+        cache, pos = self.cache, self.pos
+        steps = []
+        for j in range(inputs.shape[1]):
+            logits, cache = self._decode(self.params, cache, inputs[:, j],
+                                         pos)
+            steps.append(logits)
+            pos = pos + 1
+        return first, jnp.stack(steps)
 
-def run_disaggregated(cfg, args) -> None:
+
+def random_prompts(cfg, batch: int, prompt_len: int, seed: int = 1):
+    """The seeded (batch, prompt_len) prompts the serving entry points use."""
+    return jax.random.randint(jax.random.PRNGKey(seed), (batch, prompt_len),
+                              0, cfg.vocab_size)
+
+
+def assert_placed(tree, device, what: str) -> None:
+    """Raise unless every array of ``tree`` lives on ``device`` alone."""
+    where = {d for x in jax.tree.leaves(tree) for d in x.devices()}
+    if where != {device}:
+        raise RuntimeError(f"{what} is on {sorted(map(str, where))}, "
+                           f"but was placed on {device}")
+
+
+def run_disaggregated(cfg, *, n_devices: int, batch: int, prompt_len: int,
+                      decode: int, slice_tokens: int = 4) -> dict:
     """Prefill and decode pools on separate devices: the classic
     disaggregated-serving scenario, on the cluster runtime.  Each pool
     is an RT job pinned to its device; admission runs the cross-device
     analysis on the pinned placements before either job may start.
     Submission goes through the unified facade (``repro.sched.connect``
     → ``SchedClient``, DESIGN.md §9) — the bodies still bracket their
-    device segments on the executor face via ``client.cluster``."""
+    device segments on the executor face via ``client.cluster``.
+
+    Returns the decoded (batch, decode) tokens, both engines (whose
+    params and caches are checked to sit on their pool's device, the
+    decode cache after the handoff) and the per-device MORTs."""
     from ..sched import JobProfile, connect
 
-    n = args.n_devices
-    devs = jax.devices()
-    if len(devs) < n:
-        raise SystemExit(
-            f"--n-devices {n} but only {len(devs)} device(s) visible; "
-            f"set XLA_FLAGS=--xla_force_host_platform_device_count={n}")
-    prefill_dev, decode_dev = 0, n - 1
-    max_len = args.prompt_len + args.decode + 8
+    devs = visible_devices(n_devices)
+    prefill_dev, decode_dev = 0, n_devices - 1
+    max_len = prompt_len + decode
     pre = InferenceEngine(cfg, max_len=max_len, device=devs[prefill_dev])
     dec = InferenceEngine(cfg, params=pre.params, max_len=max_len,
                           device=devs[decode_dev])
-    toks = jax.random.randint(jax.random.PRNGKey(1),
-                              (args.batch, args.prompt_len), 0,
-                              cfg.vocab_size)
+    toks = random_prompts(cfg, batch, prompt_len)
 
     # warm-up + nominal WCETs for the admission profiles (margin below)
     pre.prefill_batch(toks)
@@ -162,10 +196,10 @@ def run_disaggregated(cfg, args) -> None:
     dec.adopt_state(pre.cache, pre.pos, pre.last_tok)
     t0 = time.perf_counter()
     jax.block_until_ready(dec.decode_chunk(4))
-    decode_ms = (time.perf_counter() - t0) * 1e3 / 4 * args.decode
+    decode_ms = (time.perf_counter() - t0) * 1e3 / 4 * decode
 
-    client = connect(n_devices=n, policy="ioctl", wait_mode="suspend",
-                     n_cpus=2, epsilon_ms=1.0)
+    client = connect(n_devices=n_devices, policy="ioctl",
+                     wait_mode="suspend", n_cpus=2, epsilon_ms=1.0)
     cluster = client.cluster
     handoff = threading.Event()
     out: dict = {}
@@ -185,7 +219,7 @@ def run_disaggregated(cfg, args) -> None:
         t = time.perf_counter()
         with cluster.device_segment(job):
             out["tokens"] = cluster.run_sliced(
-                job, dec.decode_segment(args.decode, slice_tokens=4))
+                job, dec.decode_segment(decode, slice_tokens=slice_tokens))
         times["decode_ms"] = (time.perf_counter() - t) * 1e3
 
     period = max(prefill_ms + decode_ms, 1.0) * 20
@@ -218,49 +252,61 @@ def run_disaggregated(cfg, args) -> None:
     finally:
         client.close(shutdown=True)
     cluster.assert_migration_free()
-
+    for job in (r_pre.job, r_dec.job):
+        if job.error is not None:
+            raise RuntimeError(f"{job.name} pool failed") from job.error
     if "tokens" not in out:
-        raise SystemExit("decode pool produced no tokens "
-                         "(handoff or pool failure — see traceback above)")
-    toks_out = out["tokens"]
-    per_tok = times["decode_ms"] / args.decode
+        raise RuntimeError("decode pool produced no tokens")
+    assert_placed(pre.params, devs[prefill_dev], "prefill params")
+    assert_placed(pre.cache, devs[prefill_dev], "prefill KV cache")
+    assert_placed(dec.params, devs[decode_dev], "decode params")
+    assert_placed(dec.cache, devs[decode_dev], "decode KV cache")
+
+    per_tok = times["decode_ms"] / decode
     print(f"prefill pool (device {prefill_dev}): "
-          f"{args.batch}x{args.prompt_len} in {times['prefill_ms']:.1f} ms")
-    print(f"decode pool (device {decode_dev}): {args.decode} tokens, "
+          f"{batch}x{prompt_len} in {times['prefill_ms']:.1f} ms")
+    print(f"decode pool (device {decode_dev}): {decode} tokens, "
           f"{per_tok:.2f} ms/tok "
-          f"({args.batch * 1e3 / per_tok / 1e3:.1f} tok/s aggregate)")
+          f"({batch * 1e3 / per_tok:.1f} tok/s aggregate)")
     morts = client.per_device_mort()
     print("per-device MORT (s):",
           {d: (round(v, 3) if v is not None else None)
            for d, v in morts.items()})
-    print("sample:", np.asarray(toks_out[0, :16]))
+    tokens = np.asarray(out["tokens"])
+    print("sample:", tokens[0, :16])
     print("disaggregated serve OK")
+    return {"tokens": tokens, "prefill": pre, "decode": dec,
+            "per_device_mort": morts}
 
 
-def register_serving_workloads(cfg, seed: int = 1) -> None:
+def register_serving_workloads(cfg, seed: int = 1) -> dict:
     """Register the serving segments in the durable-workload registry
     (``repro.sched.workloads``): ``serve.decode`` is a prefill + sliced
     decode whose carry (KV cache, position, emitted tokens) checkpoints
     mid-generation — a daemon submission of it survives a restart and
-    resumes decoding at the journaled slice."""
+    resumes decoding at the journaled slice.  Each engine lives on the
+    JAX device of the cluster device its job is bound to.  Returns the
+    engines as they are built, keyed (device, batch, prompt_len,
+    decode)."""
     from ..sched.workloads import register_workload
 
     engines: dict = {}
 
-    def decode_factory(batch: int = 2, prompt_len: int = 16,
-                       decode: int = 32, slice_tokens: int = 4):
-        key = (batch, prompt_len, decode)
+    def decode_factory(device: int = 0, batch: int = 2,
+                       prompt_len: int = 16, decode: int = 32,
+                       slice_tokens: int = 4):
+        key = (device, batch, prompt_len, decode)
         eng = engines.get(key)
         if eng is None:
-            eng = InferenceEngine(cfg,
-                                  max_len=prompt_len + decode + 8)
+            eng = InferenceEngine(
+                cfg, max_len=prompt_len + decode,
+                device=visible_devices(device + 1)[device])
             engines[key] = eng
-        toks = jax.random.randint(jax.random.PRNGKey(seed),
-                                  (batch, prompt_len), 0, cfg.vocab_size)
-        eng.prefill_batch(toks)
+        eng.prefill_batch(random_prompts(cfg, batch, prompt_len, seed))
         return eng.decode_segment(decode, slice_tokens=slice_tokens)
 
     register_workload("serve.decode", decode_factory)
+    return engines
 
 
 def run_daemon(cfg, args) -> None:
@@ -273,6 +319,7 @@ def run_daemon(cfg, args) -> None:
 
     from ..sched.daemon import SchedDaemon
 
+    visible_devices(args.n_devices)
     register_serving_workloads(cfg)
     daemon = SchedDaemon(args.store, args.socket,
                          n_devices=args.n_devices)
@@ -310,18 +357,18 @@ def main() -> None:
 
     entry = get(args.arch)
     cfg = entry.reduced() if args.reduced else entry.config()
+    enable_compile_cache()
     if args.daemon:
         if not args.store:
             ap.error("--daemon requires --store")
         run_daemon(cfg, args)
         return
     if args.n_devices > 1:
-        run_disaggregated(cfg, args)
+        run_disaggregated(cfg, n_devices=args.n_devices, batch=args.batch,
+                          prompt_len=args.prompt_len, decode=args.decode)
         return
-    eng = InferenceEngine(cfg, max_len=args.prompt_len + args.decode + 8)
-    toks = jax.random.randint(jax.random.PRNGKey(1),
-                              (args.batch, args.prompt_len), 0,
-                              cfg.vocab_size)
+    eng = InferenceEngine(cfg, max_len=args.prompt_len + args.decode)
+    toks = random_prompts(cfg, args.batch, args.prompt_len)
     t0 = time.perf_counter()
     eng.prefill_batch(toks)
     t1 = time.perf_counter()
@@ -331,7 +378,7 @@ def main() -> None:
     print(f"prefill {args.batch}x{args.prompt_len}: {(t1 - t0) * 1e3:.1f} ms")
     per_tok = (t2 - t1) * 1e3 / args.decode
     print(f"decode {args.decode} tokens: {per_tok:.2f} ms/tok "
-          f"({args.batch * 1e3 / per_tok / 1e3:.1f} tok/s aggregate)")
+          f"({args.batch * 1e3 / per_tok:.1f} tok/s aggregate)")
     print("sample:", np.asarray(out[0, :16]))
 
 

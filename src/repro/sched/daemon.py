@@ -39,6 +39,7 @@ import threading
 import time
 from typing import Mapping, Optional
 
+from ..runtime import enable_compile_cache
 from .admission import (AdmissionController, AdmissionDecision,
                         JobProfile, RecoveryConformanceError)
 from .cluster import ClusterExecutor
@@ -458,6 +459,7 @@ def main(argv=None) -> int:
     ap.add_argument("--auto-compact-bytes", type=int, default=None,
                     help="auto-compact the journal past this size")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     health = (HealthConfig(stall_timeout_s=args.health_stall_s,
                            fail_timeout_s=args.health_fail_s)

@@ -29,9 +29,11 @@ WORKLOADS: Dict[str, Callable[..., SlicedOp]] = {}
 
 def register_workload(name: str,
                       factory: Callable[..., SlicedOp]) -> None:
-    """Register ``factory(**kwargs) -> SlicedOp`` under ``name``.  The
-    factory must be importable in the daemon process (module-level), or
-    recovery cannot rebuild the job."""
+    """Register ``factory(device=..., **kwargs) -> SlicedOp`` under
+    ``name``; ``device`` is the cluster device the job is bound to, so a
+    factory that holds device state builds it there.  The factory must
+    be importable in the daemon process (module-level), or recovery
+    cannot rebuild the job."""
     WORKLOADS[name] = factory
 
 
@@ -82,7 +84,7 @@ def make_body(executor, job_name: str, spec: Mapping, *,
     def body(job, it):
         from . import checkpointer  # lazy: jax import
         g = offset + it
-        op = factory(**kwargs)
+        op = factory(device=job.device, **kwargs)
         carry, start = None, 0
         if (resume is not None and store is not None
                 and g == resume["iteration"]):
@@ -112,10 +114,12 @@ def make_body(executor, job_name: str, spec: Mapping, *,
 # built-in demo workloads (the recovery suite's and CI smoke's subjects)
 # --------------------------------------------------------------------------
 
-def _spin(slices: int = 8, slice_ms: float = 25.0) -> SlicedOp:
-    """Pure host-timed sliced segment: each slice sleeps ``slice_ms``
-    and bumps a counter carry — the minimal checkpointable RT job (the
-    counter proves where a resumed run actually restarted)."""
+def _spin(device: Optional[int] = None, slices: int = 8,
+          slice_ms: float = 25.0) -> SlicedOp:
+    """Pure host-timed sliced segment (``device`` unused): each slice
+    sleeps ``slice_ms`` and bumps a counter carry — the minimal
+    checkpointable RT job (the counter proves where a resumed run
+    actually restarted)."""
     def init():
         return {"done": np.zeros((), np.int64)}
 
@@ -129,10 +133,12 @@ def _spin(slices: int = 8, slice_ms: float = 25.0) -> SlicedOp:
     return SlicedOp(slices, init, step, finalize, label="demo.spin")
 
 
-def _count(total: int = 64, per_slice: int = 8) -> SlicedOp:
-    """Device-arithmetic sliced segment: accumulates ``total`` integers
-    ``per_slice`` at a time (resume-exact: the carry holds the running
-    sum and the final value is checkable as total*(total+1)/2)."""
+def _count(device: Optional[int] = None, total: int = 64,
+           per_slice: int = 8) -> SlicedOp:
+    """Host-arithmetic sliced segment (``device`` unused): accumulates
+    ``total`` integers ``per_slice`` at a time (resume-exact: the carry
+    holds the running sum and the final value is checkable as
+    total*(total+1)/2)."""
     def init():
         return {"sum": np.zeros((), np.int64)}
 

@@ -79,3 +79,36 @@ def test_decode_segment_under_executor(engine):
     # one timing sample per token slice + one for finalize
     assert len(job.stats.slice_times) == 6
     assert job.stats.mort is not None
+
+
+def test_engine_serves_lengths_off_the_decode_block():
+    """512 + 128 is no whole number of 512-token decode blocks: the engine
+    rounds its cache up (the kernel masks by length), and the compiled
+    kernel path (interpret mode here) scores the generation like the
+    reference path does."""
+    from repro.kernels import ops
+    from repro.launch.serve import random_prompts
+
+    cfg = get("smollm-135m").reduced()
+    prompt = random_prompts(cfg, 2, 512)
+    ops.set_use_pallas(True, interpret=True)
+    try:
+        eng = InferenceEngine(cfg, max_len=512 + 128)
+        assert eng.max_len == 1024
+        eng.prefill_batch(prompt)
+        toks = eng.decode_segment(128, slice_tokens=32).run()
+        assert toks.shape == (2, 128)
+        inputs = jnp.concatenate([prompt[:, -1:], toks[:, :-1]], axis=1)
+        got = eng.teacher_forced_logits(prompt, inputs)
+    finally:
+        ops.set_use_pallas(None)
+    ops.set_use_pallas(False)
+    try:
+        want = InferenceEngine(cfg, params=eng.params, max_len=640) \
+            .teacher_forced_logits(prompt, inputs)
+    finally:
+        ops.set_use_pallas(None)
+    for g, w in zip(got, want):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        # bf16 through 4 layers: the kernels and references round apart
+        assert np.linalg.norm(g - w) <= 0.05 * np.linalg.norm(w)

@@ -212,3 +212,26 @@ def test_sliced_slice_count_contract():
         op = flash_attention_sliced(q, k, v, block_q=64, block_k=32,
                                     kv_slice=kv_slice, interpret=True)
         assert op.n_slices == n, (kv_slice, op.n_slices)
+
+
+@pytest.mark.parametrize("value", ["off", "interpret"])
+def test_repro_pallas_cpu_switch_is_refused_on_tpu(monkeypatch, value):
+    """``REPRO_PALLAS=off|interpret`` steers CPU tests; on a TPU backend
+    it would hide the compiled kernels, so there it raises."""
+    monkeypatch.setenv("REPRO_PALLAS", value)
+    assert ops.use_pallas() == (value == "interpret")
+    assert ops.interpret_mode() == (value == "interpret")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="CPU-test switch"):
+        ops.use_pallas()
+    with pytest.raises(RuntimeError, match="CPU-test switch"):
+        ops.interpret_mode()
+
+
+def test_repro_pallas_on_is_allowed_on_tpu(monkeypatch):
+    monkeypatch.setenv("REPRO_PALLAS", "on")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.use_pallas() and not ops.interpret_mode()
+    monkeypatch.setenv("REPRO_PALLAS", "bogus")
+    with pytest.raises(ValueError, match="REPRO_PALLAS"):
+        ops.use_pallas()
