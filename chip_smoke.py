@@ -16,7 +16,9 @@ One chip, in one process:
       the same chip on the tokens the served path emitted, within the
       tolerance below;
   (d) the scheduling daemon in-process, ``serve.decode`` registered: three
-      requests through ``connect(<socket>)`` all finish, none unaccounted;
+      requests through ``connect(<socket>)``, each profiled as one whole
+      request with its carry checkpoints, all finish within their
+      admitted WCRT with no deadline miss and none unaccounted;
   (e) the last line: {"ok": true, "device": {...}}.
 
 ``--chips 4`` runs disaggregated serving (prefill on device 0, decode on
@@ -170,30 +172,71 @@ def compare_with_reference(cfg, dev, engine, prompt, served):
     return out
 
 
+def time_request(factory, kwargs, ckpt_dir, every):
+    """One ``serve.decode`` request as the daemon runs it: the factory's
+    prefill, every decode slice, and a carry checkpoint after every
+    ``every``-th slice.  Returns (request ms, longest slice ms,
+    [checkpoint ms])."""
+    import jax
+
+    from repro.sched import save_carry
+
+    t_req = time.perf_counter()
+    op = factory(device=0, **kwargs)
+    carry, longest, ckpts = op.init(), 0.0, []
+    for i in range(op.n_slices):
+        t0 = time.perf_counter()
+        carry = jax.block_until_ready(op.step(carry, i))
+        longest = max(longest, (time.perf_counter() - t0) * 1e3)
+        if (i + 1) % every == 0:
+            t0 = time.perf_counter()
+            save_carry(ckpt_dir, "probe", i + 1, carry)
+            ckpts.append((time.perf_counter() - t0) * 1e3)
+    jax.block_until_ready(op.finalize(carry))
+    return (time.perf_counter() - t_req) * 1e3, longest, ckpts
+
+
 def serve_through_daemon(cfg, *, batch, prompt_len, decode, slice_tokens,
-                         exec_ms, epsilon_ms, served):
-    """(d): serve.decode requests over the daemon's socket."""
+                         epsilon_ms, served):
+    """(d): serve.decode requests over the daemon's socket, each within
+    the WCRT its admission computed."""
+    import jax
     import numpy as np
 
+    from repro.launch.preemptive import MARGIN
     from repro.launch.serve import register_serving_workloads
-    from repro.sched import JobProfile, connect, save_carry
+    from repro.sched import JobProfile, connect
     from repro.sched.daemon import SchedDaemon
     from repro.sched.workloads import get_workload
 
     engines = register_serving_workloads(cfg)
-    period_ms = 4 * DAEMON_REQUESTS * exec_ms
     spec = {"name": "serve.decode",
             "kwargs": {"batch": batch, "prompt_len": prompt_len,
                        "decode": decode, "slice_tokens": slice_tokens}}
     n_slices = -(-decode // slice_tokens)
     # checkpoint the KV-cache carry twice per request, not per slice
     every = max(n_slices // 2, 1)
-    # build and compile the engine before admission, as a deployment
-    # would: a request should not pay for its server's compile
-    get_workload("serve.decode")(device=0, **spec["kwargs"]).run()
-    with tempfile.TemporaryDirectory(prefix="smoke") as store:
-        daemon = SchedDaemon(store, n_devices=1, epsilon_ms=epsilon_ms,
-                             checkpoint_every=every)
+    # a refused or failed request leaves jobs writing checkpoints here
+    # while the error propagates: the error, not the clean-up, is news
+    with tempfile.TemporaryDirectory(prefix="smoke",
+                                     ignore_cleanup_errors=True) as store:
+        # a request's carry checkpoints hold its device segment, so its
+        # profile is one whole request as the daemon runs it, measured
+        # after a first request that builds and compiles the engine (as a
+        # deployment would: a request should not pay for its server's
+        # compile)
+        factory = get_workload("serve.decode")
+        probes = [time_request(factory, spec["kwargs"],
+                               str(Path(store) / "probe"), every)
+                  for _ in range(2)]
+        request_ms, probe_slice_ms, checkpoint_ms = probes[-1]
+        exec_ms = MARGIN * request_ms
+        # ε of phase (b) bounds a slice of either job there; it stands
+        # unless these requests' own slices need a larger one
+        epsilon_ms = max(epsilon_ms, 1.0 + MARGIN * probe_slice_ms)
+        period_ms = 4 * DAEMON_REQUESTS * (exec_ms + 4 * epsilon_ms)
+        daemon = SchedDaemon(str(Path(store) / "daemon"), n_devices=1,
+                             epsilon_ms=epsilon_ms, checkpoint_every=every)
         daemon.start()
         try:
             client = connect(daemon.socket_path)
@@ -206,6 +249,8 @@ def serve_through_daemon(cfg, *, batch, prompt_len, decode, slice_tokens,
                 if not dec.accepted:
                     raise RuntimeError(f"daemon refused {name}: "
                                        f"{dec.reason} {dec.error}")
+            # the last decision prices every request against all three
+            wcrt_ms = {n: dec.wcrt[n] for n in names}
             deadline = time.monotonic() + 600
             while True:
                 jobs = client.jobs()
@@ -224,28 +269,35 @@ def serve_through_daemon(cfg, *, batch, prompt_len, decode, slice_tokens,
                                        f"{sorted(set(names) - set(done))}")
                 time.sleep(0.2)
             audit = client._backend.request("audit")
-            if audit["unaccounted"]:
-                raise RuntimeError(f"daemon lost jobs: {audit}")
-            misses = sum(jobs[n]["deadline_misses"] for n in names)
-            mort_ms = [jobs[n]["mort_s"] * 1e3 for n in names]
+            max_slice_ms = max(daemon.cluster.find_job(n).stats
+                               .max_slice_time for n in names) * 1e3
         finally:
             daemon.stop()
-        # what one checkpoint of a request's carry (the whole KV cache)
-        # costs: admission does not price it, so it shows in the MORTs
-        eng = engines[(0, batch, prompt_len, decode)]
-        t0 = time.perf_counter()
-        save_carry(store, "probe", 0, {"cache": eng.cache, "pos": eng.pos,
-                                       "tok": eng.last_tok,
-                                       "out": eng.last_out})
-        checkpoint_s = time.perf_counter() - t0
+    eng = engines[(0, batch, prompt_len, decode)]
+    out = {"finished": len(names), "unaccounted": audit["unaccounted"],
+           "request_ms": request_ms, "checkpoint_ms": checkpoint_ms,
+           "carry_bytes": sum(x.nbytes for x in jax.tree.leaves(eng.cache)),
+           "probe_max_slice_ms": probe_slice_ms, "exec_ms": exec_ms,
+           "period_ms": period_ms, "wcrt_ms": wcrt_ms,
+           "mort_ms": {n: jobs[n]["mort_s"] * 1e3 for n in names},
+           "deadline_misses": sum(jobs[n]["deadline_misses"]
+                                  for n in names),
+           "max_slice_ms": max_slice_ms, "epsilon_ms": epsilon_ms}
+    if audit["unaccounted"]:
+        raise RuntimeError(f"daemon lost jobs: {audit}")
+    late = {n: m for n, m in out["mort_ms"].items() if m > wcrt_ms[n]}
+    if late or out["deadline_misses"]:
+        raise RuntimeError(f"daemon requests broke the RT guarantee: "
+                           f"MORT over WCRT {late}, "
+                           f"{out['deadline_misses']} deadline miss(es); "
+                           f"{out}")
+    if max_slice_ms > epsilon_ms:
+        raise RuntimeError(f"a daemon slice took {max_slice_ms:.1f} ms, "
+                           f"longer than ε {epsilon_ms:.1f} ms: {out}")
     if not np.array_equal(np.asarray(eng.last_out), np.asarray(served)):
         raise RuntimeError("daemon-served tokens differ from the "
                            "connect()-served tokens of the same requests")
-    return {"finished": len(names), "unaccounted": audit["unaccounted"],
-            "period_ms": period_ms, "mort_ms": mort_ms,
-            "deadline_misses": misses,
-            "carry_checkpoints": DAEMON_REQUESTS * (n_slices // every),
-            "one_checkpoint_s": checkpoint_s}
+    return out
 
 
 def disaggregated_vs_one_chip(cfg, devs, *, batch, prompt_len, decode):
@@ -341,7 +393,10 @@ def main() -> int:
                rt_deadline_misses=res["rt_deadline_misses"],
                be_releases=res["be_completions"],
                epsilon_ms=res["epsilon_ms"], period_ms=res["period_ms"],
-               max_slice_ms=res["max_slice_ms"])
+               profiled_max_slice_ms=res["profiled_max_slice_ms"],
+               max_slice_ms=res["max_slice_ms"],
+               longest_slices_ms={n: t[-5:]
+                                  for n, t in res["slice_ms"].items()})
 
         t0, snap = time.perf_counter(), meter.snapshot()
         res_ref = compare_with_reference(cfg, devs[0], engine, prompt, served)
@@ -350,8 +405,7 @@ def main() -> int:
         t0, snap = time.perf_counter(), meter.snapshot()
         res_d = serve_through_daemon(
             cfg, **sizes, slice_tokens=SLICE_TOKENS,
-            exec_ms=res["period_ms"] / 3, epsilon_ms=res["epsilon_ms"],
-            served=served)
+            epsilon_ms=res["epsilon_ms"], served=served)
         report("daemon", meter, snap, t0, **res_d)
 
     print(f"compile total {meter.seconds:.1f} s, {meter.programs} "

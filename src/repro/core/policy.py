@@ -135,18 +135,20 @@ class Alg2State:
             else:
                 self._to_pending(job)
         else:                                       # lines 11-17
-            tau_h = self.top_running()
+            # an RT job outranks every best-effort member, whatever
+            # priority number the best-effort member carries
+            tau_h = max((j for j in self.running if job_is_rt(j)),
+                        key=job_gpu_priority, default=None)
             if tau_h is None or job_gpu_priority(job) > job_gpu_priority(tau_h):
                 self._to_running(job)
-                if tau_h is not None and job_is_rt(tau_h):
+                if tau_h is not None:
                     self._from_running(tau_h)       # preempt tau_h
                     self._to_pending(tau_h)
-                elif tau_h is not None:
-                    # best-effort members are displaced as well
-                    for be in [j for j in self.running
-                               if j is not job and not job_is_rt(j)]:
-                        self._from_running(be)
-                        self._to_pending(be)
+                # best-effort members are displaced as well
+                for be in [j for j in self.running
+                           if j is not job and not job_is_rt(j)]:
+                    self._from_running(be)
+                    self._to_pending(be)
             else:
                 self._to_pending(job)
         return {id(j) for j in before} != {id(j) for j in self.running}

@@ -26,15 +26,30 @@ from ..core.segments import SlicedOp, n_slices_for
 
 NEG_INF = -1e30
 
-#: default KV block of one grid step; caches longer than one block must be
-#: a whole number of blocks (``cache_len_for``)
+#: largest KV block of one grid step; a cache longer than it is tiled by
+#: the largest of BLOCK_K, BLOCK_K / 2, BLOCK_K / 4 that divides it
 BLOCK_K = 512
 
 
-def cache_len_for(n: int, block_k: int = BLOCK_K) -> int:
+def cache_len_for(n: int) -> int:
     """The least cache length >= ``n`` that ``flash_decode``'s blocks
-    tile: ``n`` itself up to one block, else a multiple of the block."""
-    return n if n <= block_k else -(-n // block_k) * block_k
+    tile: ``n`` itself up to one block, else a multiple of BLOCK_K / 4."""
+    step = BLOCK_K // 4
+    return n if n <= BLOCK_K else -(-n // step) * step
+
+
+def block_k_for(smax: int, block_k: int = BLOCK_K) -> int:
+    """The KV block for a cache of ``smax`` positions: the whole cache up
+    to ``block_k``, else the largest of ``block_k``, ``block_k / 2`` and
+    ``block_k / 4`` that divides it."""
+    if smax <= block_k:
+        return smax
+    for bk in (block_k, block_k // 2, block_k // 4):
+        if smax % bk == 0:
+            return bk
+    raise ValueError(f"a cache of {smax} positions is no whole number of "
+                     f"{block_k // 4}-position blocks: size it with "
+                     f"cache_len_for")
 
 
 def _block_update(len_ref, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, *,
@@ -140,8 +155,7 @@ def flash_decode(q, k_cache, v_cache, cache_len, *,
     Returns (B, H, D)."""
     b, h, d = q.shape
     smax, hkv = k_cache.shape[1], k_cache.shape[2]
-    block_k = min(block_k, smax)
-    assert smax % block_k == 0, (smax, block_k)
+    block_k = block_k_for(smax, block_k)
     n_kv = smax // block_k
     lens = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32), (b,))
     kernel = functools.partial(
@@ -178,8 +192,7 @@ def flash_decode_sliced(q, k_cache, v_cache, cache_len, *,
     tests/test_sliced_kernels.py)."""
     b, h, d = q.shape
     smax, hkv = k_cache.shape[1], k_cache.shape[2]
-    block_k = min(block_k, smax)
-    assert smax % block_k == 0, (smax, block_k)
+    block_k = block_k_for(smax, block_k)
     n_kv = smax // block_k
     n_slices = n_slices_for(n_kv, kv_slice)
     lens = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32), (b,))

@@ -9,9 +9,9 @@ The global default is platform-derived: Pallas on TPU, reference elsewhere.
 ``set_use_pallas`` overrides it (tests use interpret-mode Pallas on CPU);
 the ``REPRO_PALLAS`` environment variable provides the same override for
 subprocesses (``interpret`` → interpret-mode Pallas, as in CI's
-runtime-smoke job; ``on``/``off`` → force the dispatch).  ``interpret``
-and ``off`` are CPU-test switches: on a TPU backend they would hide the
-compiled kernels, so there they raise instead of taking effect.
+runtime-smoke job; ``off`` → the references).  Both are CPU-test
+switches: on a TPU backend they would hide the compiled kernels, so
+there they raise instead of taking effect.
 
 The ``*_sliced`` entry points return a :class:`repro.core.segments.
 SlicedOp` — the op split into K-grid-step dispatches with an explicit
@@ -33,9 +33,7 @@ _OVERRIDE: Optional[Tuple[Optional[bool], bool]] = None
 
 #: REPRO_PALLAS value -> (use_pallas, interpret); None -> platform default
 _ENV_DISPATCH = {"": (None, False), "interpret": (True, True),
-                 "on": (True, False), "1": (True, False),
-                 "true": (True, False), "off": (False, False),
-                 "0": (False, False), "false": (False, False)}
+                 "off": (False, False)}
 
 
 def set_use_pallas(value: Optional[bool], interpret: bool = False) -> None:

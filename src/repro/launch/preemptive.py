@@ -16,8 +16,11 @@ from ..core.segments import SegmentedWorkload
 
 #: RT releases per run, and how many must complete
 N_RT_RELEASES, MIN_RT_COMPLETIONS = 4, 3
-#: observations are not WCETs: the profile is inflated by this factor
+#: observations are not WCETs: the profile, and the longest profiled
+#: slice that ε is made of, are inflated by this factor
 MARGIN = 2.0
+#: profile repetitions; the first is the jit warm-up and is dropped
+PROFILE_REPS = 4
 
 
 def _wcet_ms(prof, margin: float) -> float:
@@ -34,19 +37,19 @@ def run_preemptive(infer_wl: SegmentedWorkload,
     below it), run ``N_RT_RELEASES`` RT periods with training underneath,
     and return the evidence.
 
-    The RT period is three times the margin-inflated profile plus four
-    preemption bounds; the BE job is released back to back.  Raises
-    ``SystemExit`` when admission refuses either job."""
+    ε is 1 ms of admission update plus the margin-inflated longest
+    slice of either profile.  The RT period is three times the
+    margin-inflated profile plus four ε; the BE job is released back to
+    back.  Raises ``SystemExit`` when admission refuses either job."""
     from ..sched import JobProfile, connect
 
-    # the first profile rep doubles as the jit warm-up
-    infer_prof = infer_wl.profile(reps=2)
-    train_prof = train_wl.profile(reps=2)
+    infer_prof = infer_wl.profile(reps=PROFILE_REPS)
+    train_prof = train_wl.profile(reps=PROFILE_REPS)
     # epsilon = admission-update cost + the residual of one in-flight
     # *slice* (any job's): preemption takes effect at slice boundaries,
     # so the bound is one slice — not a whole train step (DESIGN.md §6)
     max_slice = max(infer_prof.max_slice_ms, train_prof.max_slice_ms)
-    eps_ms = 1.0 + max_slice * 1.2
+    eps_ms = 1.0 + MARGIN * max_slice
     period_ms = 3.0 * (_wcet_ms(infer_prof, MARGIN) + 4 * eps_ms)
     duration_s = (N_RT_RELEASES - 0.5) * period_ms / 1e3
 
@@ -89,8 +92,11 @@ def run_preemptive(infer_wl: SegmentedWorkload,
         "rt_mort_ms": (infer.stats.mort or 0.0) * 1e3,
         "rt_deadline_misses": infer.stats.deadline_misses,
         "be_completions": train.stats.completions,
+        "profiled_max_slice_ms": max_slice,
         "max_slice_ms": max(infer.stats.max_slice_time or 0.0,
                             train.stats.max_slice_time or 0.0) * 1e3,
+        "slice_ms": {j.name: sorted(1e3 * t for t in j.stats.slice_times)
+                     for j in (infer, train)},
         "errors": {j.name: j.error for j in (infer, train)
                    if j.error is not None},
         "per_device_mort": client.per_device_mort(),
@@ -99,13 +105,19 @@ def run_preemptive(infer_wl: SegmentedWorkload,
 
 def check_preemptive(result: dict) -> None:
     """Raise unless the run kept the guarantee: no job failed, both were
-    admitted, the RT job completed ``MIN_RT_COMPLETIONS`` times within
-    its admitted WCRT and missed no deadline, and training progressed."""
+    admitted, no slice outlasted the ε the analysis was given, the RT
+    job completed ``MIN_RT_COMPLETIONS`` times within its admitted WCRT
+    and missed no deadline, and training progressed."""
     if result["errors"]:
         name, err = next(iter(result["errors"].items()))
         raise RuntimeError(f"job {name!r} failed: {err!r}") from err
     if not all(result["admitted"].values()):
         raise RuntimeError(f"admission: {result['admitted']}")
+    if result["max_slice_ms"] > result["epsilon_ms"]:
+        raise RuntimeError(f"a slice took {result['max_slice_ms']:.1f} ms, "
+                           f"longer than the preemption bound ε "
+                           f"{result['epsilon_ms']:.1f} ms admission "
+                           f"assumed")
     if result["rt_completions"] < MIN_RT_COMPLETIONS:
         raise RuntimeError(f"RT job completed {result['rt_completions']} "
                            f"time(s), fewer than {MIN_RT_COMPLETIONS}")

@@ -36,6 +36,9 @@ from ..kernels.decode_attention import cache_len_for
 from ..models import transformer
 from ..runtime import enable_compile_cache, visible_devices
 
+#: decode tokens per dispatch of the served paths (the preemption grain)
+SLICE_TOKENS = 4
+
 
 class InferenceEngine:
     def __init__(self, cfg, params=None, max_len: int = 256, seed: int = 0,
@@ -162,7 +165,7 @@ def assert_placed(tree, device, what: str) -> None:
 
 
 def run_disaggregated(cfg, *, n_devices: int, batch: int, prompt_len: int,
-                      decode: int, slice_tokens: int = 4) -> dict:
+                      decode: int) -> dict:
     """Prefill and decode pools on separate devices: the classic
     disaggregated-serving scenario, on the cluster runtime.  Each pool
     is an RT job pinned to its device; admission runs the cross-device
@@ -219,7 +222,7 @@ def run_disaggregated(cfg, *, n_devices: int, batch: int, prompt_len: int,
         t = time.perf_counter()
         with cluster.device_segment(job):
             out["tokens"] = cluster.run_sliced(
-                job, dec.decode_segment(decode, slice_tokens=slice_tokens))
+                job, dec.decode_segment(decode, slice_tokens=SLICE_TOKENS))
         times["decode_ms"] = (time.perf_counter() - t) * 1e3
 
     period = max(prefill_ms + decode_ms, 1.0) * 20
@@ -294,7 +297,7 @@ def register_serving_workloads(cfg, seed: int = 1) -> dict:
 
     def decode_factory(device: int = 0, batch: int = 2,
                        prompt_len: int = 16, decode: int = 32,
-                       slice_tokens: int = 4):
+                       slice_tokens: int = SLICE_TOKENS):
         key = (device, batch, prompt_len, decode)
         eng = engines.get(key)
         if eng is None:

@@ -143,6 +143,14 @@ def save_carry(ckpt_dir: str, label: str, slice_idx: int,
     return save(os.path.join(ckpt_dir, f"carry_{label}"), slice_idx, carry)
 
 
+def load_carry(ckpt_dir: str, label: str, slice_idx: int,
+               like: Any) -> Any:
+    """The snapshot ``save_carry`` took for ``label`` after ``slice_idx``
+    completed slices, restored into the structure of ``like``."""
+    return restore(os.path.join(ckpt_dir, f"carry_{label}"), like,
+                   step=slice_idx)
+
+
 def latest_carry(ckpt_dir: str, label: str, like: Any
                  ) -> Optional[tuple]:
     """(slice_idx, carry) of the latest snapshot for ``label``, restored

@@ -16,9 +16,9 @@ startup runs the recovery path:
   2. **rebind** — every recovered job is re-bound to its journaled
      device (the immutable binding survives the crash, so the
      migration-free invariant holds *across restarts*);
-  3. **resume** — a job that was mid-segment restarts from its latest
+  3. **resume** — a job that was mid-segment restarts from the
      checkpointed carry at the journaled slice index
-     (``checkpointer.latest_carry``), not from scratch; remaining
+     (``checkpointer.load_carry``), not from scratch; remaining
      iterations then run normally.
 
 Run it:

@@ -88,11 +88,13 @@ def make_body(executor, job_name: str, spec: Mapping, *,
         carry, start = None, 0
         if (resume is not None and store is not None
                 and g == resume["iteration"]):
-            restored = checkpointer.latest_carry(
-                store.carry_dir(job_name), job_name, op.init())
-            if restored is not None:
-                start, carry = restored
-                store.record_resume(job_name, g, start)
+            # the journal, not the newest snapshot on disk, says where to
+            # resume: a kill between a snapshot and its journal record
+            # leaves a snapshot no record acknowledges
+            start = resume["slice"]
+            carry = checkpointer.load_carry(store.carry_dir(job_name),
+                                            job_name, start, op.init())
+            store.record_resume(job_name, g, start)
         ckpt = None
         if store is not None:
             def ckpt(i, c):

@@ -14,7 +14,8 @@ import pytest
 from repro import runtime
 from repro.core.segments import SegmentedWorkload, SlicedOp
 from repro.launch.fleet import default_fleet, launch_fleet
-from repro.launch.preemptive import check_preemptive, run_preemptive
+from repro.launch.preemptive import (PROFILE_REPS, check_preemptive,
+                                     run_preemptive)
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -81,9 +82,10 @@ def _host_op(label, n_slices=2, slice_s=0.002, fail_after=None):
 
 def test_failed_job_fails_the_preemptive_check():
     infer = SegmentedWorkload("infer").device(_host_op("decode"))
-    # profile(reps=2) builds the op twice; the scheduled run then fails
-    train = SegmentedWorkload("train").device(_host_op("train",
-                                                       fail_after=2))
+    # the profile builds the op PROFILE_REPS times; the scheduled run
+    # then fails
+    train = SegmentedWorkload("train").device(
+        _host_op("train", fail_after=PROFILE_REPS))
     result = run_preemptive(infer, train)
     assert set(result["errors"]) == {"train"}
     with pytest.raises(RuntimeError, match="'train' failed"):
@@ -91,12 +93,13 @@ def test_failed_job_fails_the_preemptive_check():
 
 
 def test_preemptive_check_passes_a_clean_run():
-    # slices long enough that thread wake-up jitter on a loaded host stays
-    # inside the margin admission adds to the measured profile
+    # slices long enough that thread wake-up jitter on a loaded host
+    # (tens of ms) stays inside the margin admission adds to the measured
+    # profile and to the longest slice that ε is made of
     infer = SegmentedWorkload("infer").device(_host_op("decode",
-                                                       slice_s=0.02))
+                                                       slice_s=0.1))
     train = SegmentedWorkload("train").device(_host_op("train",
-                                                       slice_s=0.02))
+                                                       slice_s=0.1))
     result = run_preemptive(infer, train)
     check_preemptive(result)
     assert result["rt_mort_ms"] <= result["wcrt_ms"]

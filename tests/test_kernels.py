@@ -9,7 +9,8 @@ import pytest
 from _optional import given, settings, st  # hypothesis or skip-shims
 
 from repro.kernels import ref
-from repro.kernels.decode_attention import flash_decode
+from repro.kernels.decode_attention import (block_k_for, cache_len_for,
+                                            flash_decode)
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.mamba_scan import mamba_scan_pallas
 from repro.kernels.rwkv6 import rwkv6_scan_pallas
@@ -131,6 +132,35 @@ def test_flash_decode_per_batch_lengths():
     lens = jnp.array([10, 64, 128], jnp.int32)
     want = ref.decode_attention(q, kc, vc, lens)
     got = flash_decode(q, kc, vc, lens, block_k=32, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("n,smax,block", [
+    (200, 200, 200),          # one block: the whole cache
+    (512, 512, 512),
+    (640, 640, 128),          # 512 + 128: blocks shrink, the cache does not
+    (700, 768, 256),
+    (1024, 1024, 512),
+])
+def test_cache_len_and_block_for(n, smax, block):
+    assert cache_len_for(n) == smax
+    assert block_k_for(smax) == block
+
+
+def test_block_k_for_refuses_an_untiled_cache():
+    with pytest.raises(ValueError, match="cache_len_for"):
+        block_k_for(600)
+
+
+def test_flash_decode_tiles_a_cache_off_the_default_block():
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = _rand(ks[0], (2, 9, 64), jnp.float32)
+    kc = _rand(ks[1], (2, 640, 3, 64), jnp.float32)
+    vc = _rand(ks[2], (2, 640, 3, 64), jnp.float32)
+    lens = jnp.array([513, 640], jnp.int32)
+    want = ref.decode_attention(q, kc, vc, lens)
+    got = flash_decode(q, kc, vc, lens, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 

@@ -220,6 +220,33 @@ def test_alg2_end_from_pending_does_not_corrupt_runlist():
     assert [j.name for j in st_.running] == ["wait"]
 
 
+def test_alg2_rt_arrival_displaces_best_effort_of_any_number():
+    """Regression (found by this fuzzer): an RT arrival used to be ranked
+    against the highest-numbered member of task_running even when that
+    member was best-effort; behind it the RT job waited in task_pending,
+    and the next end() handed it the runlist beside the best-effort
+    member.  An RT arrival outranks every best-effort member."""
+    from repro.core import Alg2State
+
+    class Stub:
+        def __init__(self, name, prio, rt):
+            self.name = name
+            self.is_rt = rt
+            self.priority = self.device_priority = prio
+            self.gpu_pending = False
+
+    be_low, be_high, rt = (Stub("be1", 1, False), Stub("be6", 6, False),
+                           Stub("rt5", 5, True))
+    st_ = Alg2State()
+    st_.add(be_low)
+    st_.add(be_high)
+    assert st_.add(rt) is True
+    assert [j.name for j in st_.running] == ["rt5"]
+    assert {j.name for j in st_.pending} == {"be1", "be6"}
+    st_.remove(rt)
+    assert {j.name for j in st_.running} == {"be1", "be6"}
+
+
 def test_best_effort_device_priority_is_ignored():
     """Regression (found by this fuzzer): a best-effort RTJob built with
     an explicit high device_priority used to outrank RT arrivals in

@@ -82,10 +82,10 @@ def test_decode_segment_under_executor(engine):
 
 
 def test_engine_serves_lengths_off_the_decode_block():
-    """512 + 128 is no whole number of 512-token decode blocks: the engine
-    rounds its cache up (the kernel masks by length), and the compiled
-    kernel path (interpret mode here) scores the generation like the
-    reference path does."""
+    """512 + 128 is no whole number of 512-token decode blocks: the
+    kernel tiles the 640-position cache with 128-position blocks instead
+    of the engine padding it, and the compiled kernel path (interpret mode
+    here) scores the generation like the reference path does."""
     from repro.kernels import ops
     from repro.launch.serve import random_prompts
 
@@ -94,7 +94,7 @@ def test_engine_serves_lengths_off_the_decode_block():
     ops.set_use_pallas(True, interpret=True)
     try:
         eng = InferenceEngine(cfg, max_len=512 + 128)
-        assert eng.max_len == 1024
+        assert eng.max_len == 640
         eng.prefill_batch(prompt)
         toks = eng.decode_segment(128, slice_tokens=32).run()
         assert toks.shape == (2, 128)

@@ -228,10 +228,7 @@ def test_repro_pallas_cpu_switch_is_refused_on_tpu(monkeypatch, value):
         ops.interpret_mode()
 
 
-def test_repro_pallas_on_is_allowed_on_tpu(monkeypatch):
+def test_repro_pallas_unknown_value_is_refused(monkeypatch):
     monkeypatch.setenv("REPRO_PALLAS", "on")
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert ops.use_pallas() and not ops.interpret_mode()
-    monkeypatch.setenv("REPRO_PALLAS", "bogus")
     with pytest.raises(ValueError, match="REPRO_PALLAS"):
         ops.use_pallas()

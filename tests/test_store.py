@@ -422,3 +422,38 @@ def test_gc_skips_step_held_by_concurrent_restore(tmp_path):
         assert out["w"].tolist() == [0, 0, 0]
     ckpt._gc()                       # reader gone: next pass collects it
     assert not os.path.isdir(os.path.join(str(tmp_path), "step_00000000"))
+
+
+def test_resume_takes_the_journaled_carry_not_a_newer_snapshot(tmp_path):
+    """A kill between a carry snapshot and its journal record leaves a
+    snapshot that no record acknowledges: recovery resumes at the
+    journaled slice, from that slice's snapshot."""
+    import contextlib
+    from types import SimpleNamespace
+
+    from repro.sched import checkpointer
+    from repro.sched.workloads import get_workload, make_body
+
+    spec = {"name": "demo.count", "kwargs": {"total": 64, "per_slice": 8}}
+    store = JobStore(str(tmp_path))
+    op = get_workload("demo.count")(**spec["kwargs"])
+    carry = op.init()
+    for i in range(5):
+        carry = op.step(carry, i)
+        checkpointer.save_carry(store.carry_dir("j"), "j", i + 1, carry)
+        if i < 4:                       # slice 5's record never landed
+            store.record_carry("j", 0, i + 1)
+    seen = {}
+
+    class Executor:
+        @contextlib.contextmanager
+        def device_segment(self, job):
+            yield
+
+        def run_sliced(self, job, op, *, carry, start, **_):
+            seen.update(start=start, sum=int(carry["sum"]))
+
+    body = make_body(Executor(), "j", spec, store=store,
+                     resume={"iteration": 0, "slice": 4})
+    body(SimpleNamespace(device=None), 0)
+    assert seen == {"start": 4, "sum": sum(range(1, 33))}
