@@ -373,14 +373,19 @@ class SegmentedWorkload:
     def run(self, executor, job) -> List[Any]:
         """Execute one release: host segments inline, device segments
         through the executor's sliced dispatch loop (admission re-checked
-        before every slice).  Returns the device segments' outputs."""
+        before every slice).  Returns the device segments' outputs.
+        Building a device segment's op is the span
+        ``sched.segment_build`` (DESIGN.md §13)."""
+        from ..sched.spans import span      # jax-free at import time
         outs = []
         for e in self._entries:
             if e.kind == "host":
                 e.fn()
             else:
                 with executor.device_segment(job):
-                    outs.append(executor.run_sliced(job, e.fn()))
+                    with span("sched.segment_build", op=e.label):
+                        op = e.fn()
+                    outs.append(executor.run_sliced(job, op))
         return outs
 
     # -- measurement -------------------------------------------------------

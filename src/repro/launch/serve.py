@@ -35,6 +35,7 @@ from ..core.segments import SlicedOp, n_slices_for
 from ..kernels.decode_attention import cache_len_for
 from ..models import transformer
 from ..runtime import enable_compile_cache, visible_devices
+from ..sched import spans
 
 #: decode tokens per dispatch of the served paths (the preemption grain)
 SLICE_TOKENS = 4
@@ -109,13 +110,17 @@ class InferenceEngine:
         def step(carry, i):
             cache, pos, tok, out = (carry["cache"], carry["pos"],
                                     carry["tok"], carry["out"])
-            for t in range(i * slice_tokens,
-                           min((i + 1) * slice_tokens, n)):
-                logits, cache = self._decode(self.params, cache, tok, pos)
-                pos = pos + 1
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                out = jax.lax.dynamic_update_slice(
-                    out, tok[:, None], (0, t))
+            lo, hi = i * slice_tokens, min((i + 1) * slice_tokens, n)
+            # the host's enqueue of the slice's programs: it returns
+            # before the device is done with them
+            with spans.span("serve.decode_enqueue", tokens=hi - lo):
+                for t in range(lo, hi):
+                    logits, cache = self._decode(self.params, cache, tok,
+                                                 pos)
+                    pos = pos + 1
+                    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    out = jax.lax.dynamic_update_slice(
+                        out, tok[:, None], (0, t))
             return {"cache": cache, "pos": pos, "tok": tok, "out": out}
 
         def finalize(carry):

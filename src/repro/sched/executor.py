@@ -44,7 +44,7 @@ from typing import Callable, List, Optional, Union
 import jax
 
 from ..core.policy import (LEGACY_MODES, SchedulingPolicy, make_policy)
-from . import faultinject
+from . import faultinject, spans
 from .fault import DeviceFailedError, DeviceHealth, JobEvicted
 from .job import RTJob
 
@@ -232,16 +232,16 @@ class DeviceExecutor:
     # executor's API; device_segment() is the public path.
     # ------------------------------------------------------------------
     def _ioctl_add(self, job: RTJob) -> None:
-        t0 = time.perf_counter()
-        rewrote = self.policy.runtime_segment_begin(job)
-        self.update_times.append(time.perf_counter() - t0)
+        with spans.span("sched.runlist_update", which="add") as upd:
+            rewrote = self.policy.runtime_segment_begin(job)
+        self.update_times.append(upd.seconds)
         self._emit_alg2("begin", job, rewrote)
         self._cv.notify_all()
 
     def _ioctl_remove(self, job: RTJob) -> None:
-        t0 = time.perf_counter()
-        rewrote = self.policy.runtime_segment_end(job)
-        self.update_times.append(time.perf_counter() - t0)
+        with spans.span("sched.runlist_update", which="end") as upd:
+            rewrote = self.policy.runtime_segment_end(job)
+        self.update_times.append(upd.seconds)
         self._emit_alg2("end", job, rewrote)
         self._cv.notify_all()
 
@@ -272,36 +272,40 @@ class DeviceExecutor:
         # admission comes back, "dispatch" at every admission pass — all
         # under the mutex, so a dispatch event is totally ordered against
         # the runlist updates that justified it (conformance harness).
-        blocked = False
+        # The span sched.policy_wait runs from the first denied check to
+        # admission, and only when one was denied.
         if self.wait_mode == "busy":
-            while True:
-                with self._mutex:
-                    self._check_containment(job)
-                    if self._admitted(job):
-                        if blocked:
+            with self._mutex:
+                self._check_containment(job)
+                if self._admitted(job):
+                    self._emit("dispatch", job, uid=job.uid)
+                    return
+                self._emit("preempt", job)
+            with spans.span("sched.policy_wait"):
+                while True:
+                    # busy-wait: a sub-poll-interval yield, not sleep(0) —
+                    # a zero-sleep spin churns the GIL hard enough to
+                    # starve the *running* job's thread on CPython, which
+                    # shows up as cross-device interference a real
+                    # spinning core would never cause
+                    time.sleep(0.0005)
+                    with self._mutex:
+                        self._check_containment(job)
+                        if self._admitted(job):
                             self._emit("resume", job)
-                        self._emit("dispatch", job, uid=job.uid)
-                        return
-                    if not blocked:
-                        blocked = True
-                        self._emit("preempt", job)
-                # busy-wait: a sub-poll-interval yield, not sleep(0) — a
-                # zero-sleep spin churns the GIL hard enough to starve
-                # the *running* job's thread on CPython, which shows up
-                # as cross-device interference a real spinning core
-                # would never cause
-                time.sleep(0.0005)
+                            self._emit("dispatch", job, uid=job.uid)
+                            return
         else:
             with self._cv:
-                while True:
-                    self._check_containment(job)
-                    if self._admitted(job):
-                        break
-                    if not blocked:
-                        blocked = True
-                        self._emit("preempt", job)
-                    self._cv.wait(timeout=0.05)
-                if blocked:
+                self._check_containment(job)
+                if not self._admitted(job):
+                    self._emit("preempt", job)
+                    with spans.span("sched.policy_wait"):
+                        while True:
+                            self._cv.wait(timeout=0.05)
+                            self._check_containment(job)
+                            if self._admitted(job):
+                                break
                     self._emit("resume", job)
                 self._emit("dispatch", job, uid=job.uid)
 
@@ -346,7 +350,9 @@ class DeviceExecutor:
         """Dispatch one device program for ``job``; blocks until the result
         is ready.  Re-checks admission first (preemption point)."""
         self._wait_admitted(job)
-        with self._device_lock:
+        with spans.span("sched.device_wait"):
+            self._device_lock.acquire()
+        try:
             self.dispatches += 1
             if self.health is not None:
                 self.health.slice_begin(job.name, -1)
@@ -365,6 +371,8 @@ class DeviceExecutor:
             finally:
                 if self.health is not None:
                     self.health.slice_end()
+        finally:
+            self._device_lock.release()
         return out
 
     def run_sliced(self, job: RTJob, op, *,
@@ -389,39 +397,45 @@ class DeviceExecutor:
             carry = op.init()
         for i in range(start, op.n_slices):
             self._wait_admitted(job)
-            carry = self._dispatch_slice(job, op.step, carry, i)
+            carry = self._dispatch_slice(job, op.step, carry, i, op.label)
             if checkpoint is not None and checkpoint_every > 0 \
                     and (i + 1) % checkpoint_every == 0:
                 checkpoint(i + 1, carry)
         self._wait_admitted(job)
         return self._dispatch_slice(job, lambda c, _i: op.finalize(c),
-                                    carry, op.n_slices)
+                                    carry, op.n_slices, op.label)
 
-    def _dispatch_slice(self, job: RTJob, step, carry, i: int):
+    def _dispatch_slice(self, job: RTJob, step, carry, i: int,
+                        label: str):
         """One slice under the device lock: the health heartbeat is
         armed for exactly the in-flight window (a hung kernel reads as
         a stalled armed beat), the fault injector fires at the dispatch
         point, and a slice exception lands in the device's health
-        record before propagating (DESIGN.md §10)."""
-        with self._device_lock:
+        record before propagating (DESIGN.md §10).  The span
+        ``sched.slice`` is the interval ``slice_times`` records."""
+        with spans.span("sched.device_wait"):
+            self._device_lock.acquire()
+        try:
             self.dispatches += 1
             if self.health is not None:
                 self.health.slice_begin(job.name, i)
-            t0 = time.perf_counter()
-            try:
-                if self.fault_injector is not None:
-                    self.fault_injector.fire(device=self.device_index,
-                                             job=job.name, slice_idx=i)
-                out = step(carry, i)
-                jax.block_until_ready(out)
-            except (DeviceFailedError, JobEvicted):
-                raise
-            except Exception as e:  # noqa: BLE001 — health accounting
-                if self.health is not None:
-                    self.health.record_error(job.name, e)
-                raise
-            finally:
-                if self.health is not None:
-                    self.health.slice_end()
-            job.stats.slice_times.append(time.perf_counter() - t0)
+            with spans.span("sched.slice", i=i, op=label) as sl:
+                try:
+                    if self.fault_injector is not None:
+                        self.fault_injector.fire(device=self.device_index,
+                                                 job=job.name, slice_idx=i)
+                    out = step(carry, i)
+                    jax.block_until_ready(out)
+                except (DeviceFailedError, JobEvicted):
+                    raise
+                except Exception as e:  # noqa: BLE001 — health accounting
+                    if self.health is not None:
+                        self.health.record_error(job.name, e)
+                    raise
+                finally:
+                    if self.health is not None:
+                        self.health.slice_end()
+            job.stats.slice_times.append(sl.seconds)
+        finally:
+            self._device_lock.release()
         return out

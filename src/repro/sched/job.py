@@ -18,8 +18,9 @@ import itertools
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Callable, List, Optional
+from typing import Callable, Deque, List, Optional
 
+from . import spans
 from .fault import FaultContained
 
 BEST_EFFORT = -1_000_000
@@ -39,6 +40,8 @@ class JobStats:
     response_times: List[float] = field(default_factory=list)
     deadline_misses: int = 0
     slice_times: List[float] = field(default_factory=list)  # seconds
+    #: the last ``spans.BUFFER`` spans of this job's releases
+    spans: Deque[spans.Span] = field(default_factory=spans.new_buffer)
 
     @property
     def mort(self) -> Optional[float]:
@@ -141,13 +144,22 @@ class RTJob:
             now = time.monotonic()
             if now < next_release:
                 time.sleep(next_release - now)
+            # the response runs from the due release, so a release that
+            # starts late (its predecessor overran) carries the lateness;
+            # the schedule itself moves on from the actual start
+            due = next_release
             self.release_time = max(next_release, now)
             next_release = self.release_time + self.period_s
             self.state = JobState.RUNNING
             self.stats.releases += 1
-            executor.on_job_start(self)
             try:
-                self.body(self, it)
+                with spans.release(self.stats.spans, job=self.name, k=it,
+                                   due=int(due * 1e9)):
+                    executor.on_job_start(self)
+                    try:
+                        self.body(self, it)
+                    finally:
+                        executor.on_job_complete(self)
             except FaultContained as e:
                 # orderly platform stop (eviction / device fail-over):
                 # the iteration did not complete — no completion, no
@@ -160,9 +172,7 @@ class RTJob:
                 # thread whose traceback nobody joined on
                 self.error = e
                 break
-            finally:
-                executor.on_job_complete(self)
-            resp = time.monotonic() - self.release_time
+            resp = time.monotonic() - due
             self.stats.completions += 1
             self.stats.response_times.append(resp)
             if resp > self.deadline_s and self.is_rt:
