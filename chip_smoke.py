@@ -203,7 +203,7 @@ def serve_through_daemon(cfg, *, batch, prompt_len, decode, slice_tokens,
     import jax
     import numpy as np
 
-    from repro.launch.preemptive import MARGIN
+    from repro.launch import preemptive
     from repro.launch.serve import register_serving_workloads
     from repro.sched import JobProfile, connect
     from repro.sched.daemon import SchedDaemon
@@ -230,10 +230,10 @@ def serve_through_daemon(cfg, *, batch, prompt_len, decode, slice_tokens,
                                str(Path(store) / "probe"), every)
                   for _ in range(2)]
         request_ms, probe_slice_ms, checkpoint_ms = probes[-1]
-        exec_ms = MARGIN * request_ms
+        exec_ms = preemptive.MARGIN * request_ms
         # ε of phase (b) bounds a slice of either job there; it stands
         # unless these requests' own slices need a larger one
-        epsilon_ms = max(epsilon_ms, 1.0 + MARGIN * probe_slice_ms)
+        epsilon_ms = max(epsilon_ms, preemptive.epsilon_ms(probe_slice_ms))
         period_ms = 4 * DAEMON_REQUESTS * (exec_ms + 4 * epsilon_ms)
         daemon = SchedDaemon(str(Path(store) / "daemon"), n_devices=1,
                              epsilon_ms=epsilon_ms, checkpoint_every=every)

@@ -141,7 +141,7 @@ def attention_sliced(q, k, v, *, causal: bool = True,
                      kv_slice: int = 1) -> SlicedOp:
     """Flash attention as a SlicedOp: ``kv_slice`` kv-block grid steps per
     dispatch, explicit (m, l, acc) carry between dispatches.  Value-
-    identical to :func:`attention` on the Pallas path."""
+    identical to :func:`attention` on the Pallas path at equal blocks."""
     from .flash_attention import flash_attention_sliced
     return flash_attention_sliced(
         q, k, v, causal=causal, window=window, q_offset=q_offset,

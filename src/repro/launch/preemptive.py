@@ -21,6 +21,18 @@ N_RT_RELEASES, MIN_RT_COMPLETIONS = 4, 3
 MARGIN = 2.0
 #: profile repetitions; the first is the jit warm-up and is dropped
 PROFILE_REPS = 4
+#: a pause of the whole serving process that the host imposes, a few times
+#: a minute outside its longer freezes: 107-112 ms on a TPU v5e host, with
+#: every thread stopped (PERF.md §6).  It stretches whatever slice it
+#: meets by its own length, however short the slice, so ε adds it once
+#: instead of scaling it with the slice
+HOST_PAUSE_MS = 120.0
+
+
+def epsilon_ms(max_slice_ms: float) -> float:
+    """ε: 1 ms of runlist update, the margin-inflated longest profiled
+    slice of any job, and one host pause."""
+    return 1.0 + MARGIN * max_slice_ms + HOST_PAUSE_MS
 
 
 def _wcet_ms(prof, margin: float) -> float:
@@ -37,19 +49,19 @@ def run_preemptive(infer_wl: SegmentedWorkload,
     below it), run ``N_RT_RELEASES`` RT periods with training underneath,
     and return the evidence.
 
-    ε is 1 ms of admission update plus the margin-inflated longest
-    slice of either profile.  The RT period is three times the
-    margin-inflated profile plus four ε; the BE job is released back to
-    back.  Raises ``SystemExit`` when admission refuses either job."""
+    ε is :func:`epsilon_ms` of the longest slice of either profile.  The
+    RT period is three times the margin-inflated profile plus four ε; the
+    BE job is released back to back.  Raises ``SystemExit`` when admission refuses either job."""
     from ..sched import JobProfile, connect
 
     infer_prof = infer_wl.profile(reps=PROFILE_REPS)
     train_prof = train_wl.profile(reps=PROFILE_REPS)
     # epsilon = admission-update cost + the residual of one in-flight
-    # *slice* (any job's): preemption takes effect at slice boundaries,
-    # so the bound is one slice — not a whole train step (DESIGN.md §6)
+    # *slice* (any job's), as a host pause may stretch it: preemption
+    # takes effect at slice boundaries, so the bound is one slice — not a
+    # whole train step (DESIGN.md §6)
     max_slice = max(infer_prof.max_slice_ms, train_prof.max_slice_ms)
-    eps_ms = 1.0 + MARGIN * max_slice
+    eps_ms = epsilon_ms(max_slice)
     period_ms = 3.0 * (_wcet_ms(infer_prof, MARGIN) + 4 * eps_ms)
     duration_s = (N_RT_RELEASES - 0.5) * period_ms / 1e3
 

@@ -14,7 +14,8 @@ import pytest
 from repro import runtime
 from repro.core.segments import SegmentedWorkload, SlicedOp
 from repro.launch.fleet import default_fleet, launch_fleet
-from repro.launch.preemptive import (PROFILE_REPS, check_preemptive,
+from repro.launch.preemptive import (HOST_PAUSE_MS, MARGIN, PROFILE_REPS,
+                                     check_preemptive, epsilon_ms,
                                      run_preemptive)
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -103,6 +104,16 @@ def test_preemptive_check_passes_a_clean_run():
     result = run_preemptive(infer, train)
     check_preemptive(result)
     assert result["rt_mort_ms"] <= result["wcrt_ms"]
+    assert result["epsilon_ms"] == epsilon_ms(result["profiled_max_slice_ms"])
+
+
+def test_epsilon_covers_a_slice_a_host_pause_stretches():
+    # a pause stops every thread for its whole length, so a slice it meets
+    # lasts up to the slice and the pause, however short the slice
+    for slice_ms in (0.5, 64.0, 160.0):
+        eps = epsilon_ms(slice_ms)
+        assert eps >= slice_ms + HOST_PAUSE_MS
+        assert eps == pytest.approx(1.0 + MARGIN * slice_ms + HOST_PAUSE_MS)
 
 
 _PLACEMENT = r"""

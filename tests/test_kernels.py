@@ -11,7 +11,8 @@ from _optional import given, settings, st  # hypothesis or skip-shims
 from repro.kernels import ref
 from repro.kernels.decode_attention import (block_k_for, cache_len_for,
                                             flash_decode)
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import (VMEM_BUDGET, _vmem_bytes,
+                                           attention_plan, flash_attention)
 from repro.kernels.mamba_scan import mamba_scan_pallas
 from repro.kernels.rwkv6 import rwkv6_scan_pallas
 
@@ -27,16 +28,33 @@ def _rand(key, shape, dtype):
 # flash attention
 # ---------------------------------------------------------------------------
 
+def _case(*shape, block=64):
+    """An oracle case; cases at the 64-blocks keep their ids from before
+    blocks were a parameter, the rest say ``default``."""
+    name = "-".join(map(str, shape))
+    return pytest.param(*shape, block,
+                        id=name if block == 64 else f"{name}-default")
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("b,sq,sk,h,hkv,d,causal,window", [
-    (2, 128, 128, 4, 2, 64, True, None),
-    (1, 256, 256, 2, 2, 64, True, 96),       # sliding window
-    (2, 128, 256, 4, 4, 128, True, None),    # q_offset (chunked prefill)
-    (1, 128, 128, 2, 1, 64, False, None),    # non-causal (cross-attn)
-    (1, 64, 64, 8, 8, 128, True, None),
+@pytest.mark.parametrize("b,sq,sk,h,hkv,d,causal,window,block", [
+    _case(2, 128, 128, 4, 2, 64, True, None),
+    _case(1, 256, 256, 2, 2, 64, True, 96),       # sliding window
+    _case(2, 128, 256, 4, 4, 128, True, None),    # q_offset (chunked prefill)
+    _case(1, 128, 128, 2, 1, 64, False, None),    # non-causal (cross-attn)
+    _case(1, 64, 64, 8, 8, 128, True, None),
+    # blocks from attention_plan
+    _case(2, 128, 128, 4, 2, 32, True, None, block=None),
+    _case(1, 256, 256, 2, 2, 32, False, None, block=None),
+    _case(1, 512, 512, 3, 1, 32, True, None, block=None),     # GQA
+    _case(1, 512, 512, 2, 2, 32, True, 200, block=None),
+    _case(1, 1024, 1024, 4, 2, 32, True, None, block=None),
+    _case(1, 1024, 1024, 2, 1, 32, False, None, block=None),
+    _case(1, 1024, 1024, 2, 2, 32, True, 300, block=None),
+    _case(1, 512, 1024, 2, 2, 32, True, None, block=None),    # q_offset
 ])
 def test_flash_attention_vs_oracle(b, sq, sk, h, hkv, d, causal, window,
-                                   dtype):
+                                   block, dtype):
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = _rand(ks[0], (b, sq, h, d), dtype)
     k = _rand(ks[1], (b, sk, hkv, d), dtype)
@@ -45,21 +63,21 @@ def test_flash_attention_vs_oracle(b, sq, sk, h, hkv, d, causal, window,
     want = ref.attention_dense(q, k, v, causal=causal, window=window,
                                q_offset=qo)
     got = flash_attention(q, k, v, causal=causal, window=window,
-                          q_offset=qo, block_q=64, block_k=64,
+                          q_offset=qo, block_q=block, block_k=block,
                           interpret=True)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **TOL[dtype])
 
 
-def test_flash_attention_gradients_match_oracle():
+def _check_gradients(s, d, block):
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
-    q = _rand(ks[0], (1, 64, 2, 32), jnp.float32)
-    k = _rand(ks[1], (1, 64, 2, 32), jnp.float32)
-    v = _rand(ks[2], (1, 64, 2, 32), jnp.float32)
+    q = _rand(ks[0], (1, s, 2, d), jnp.float32)
+    k = _rand(ks[1], (1, s, 2, d), jnp.float32)
+    v = _rand(ks[2], (1, s, 2, d), jnp.float32)
 
     def f_kernel(q, k, v):
-        return flash_attention(q, k, v, interpret=True, block_q=32,
-                               block_k=32).sum()
+        return flash_attention(q, k, v, interpret=True, block_q=block,
+                               block_k=block).sum()
 
     def f_ref(q, k, v):
         return ref.attention_dense(q, k, v).sum()
@@ -69,6 +87,80 @@ def test_flash_attention_gradients_match_oracle():
     for a, b_ in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=2e-4, atol=2e-4)
+
+
+def test_flash_attention_gradients_match_oracle():
+    _check_gradients(64, 32, 32)
+
+
+def test_flash_attention_gradients_at_default_blocks():
+    _check_gradients(1024, 16, None)
+
+
+@pytest.mark.parametrize("s", [64, 100, 128, 256, 384, 512, 640, 1024,
+                               2048, 4096])
+@pytest.mark.parametrize("d", [64, 128])
+def test_attention_plan_blocks_divide_the_sequence(s, d):
+    plan = attention_plan(s, s, d)
+    assert s % plan.block_q == 0 and s % plan.block_k == 0
+    assert plan.steps == (s // plan.block_q) * (s // plan.block_k)
+    # the largest blocks that tile: the whole sequence up to 512
+    assert plan.block_q == plan.block_k == (s if s <= 512 else 512
+                                            if s % 512 == 0 else 128)
+
+
+@pytest.mark.parametrize("s", [136, 300, 1000])
+def test_attention_plan_refuses_sequences_off_the_lanes(s):
+    # past 128 positions a block is a multiple of 128, as the kernel's
+    # score tiles need
+    with pytest.raises(ValueError, match="whole number"):
+        attention_plan(s, s, 64)
+
+
+def test_attention_plan_takes_both_blocks_or_neither():
+    with pytest.raises(ValueError, match="both blocks"):
+        attention_plan(512, 512, 64, block_q=128)
+
+
+@pytest.mark.parametrize("s,block,steps,computed", [
+    (128, None, 1, 1),
+    (512, None, 1, 1),
+    (1024, None, 4, 3),
+    (2048, None, 16, 10),
+    (512, 128, 16, 10),     # the kernel's blocks before they came from
+    (1024, 128, 64, 36),    # the shape: still what a caller passing them gets
+    (512, 256, 4, 3),
+])
+def test_attention_plan_computes_the_lower_triangle(s, block, steps,
+                                                    computed):
+    plan = attention_plan(s, s, 64, causal=True, block_q=block,
+                          block_k=block)
+    n = s // plan.block_k
+    assert plan.block_q == plan.block_k
+    assert (plan.steps, plan.computed) == (steps, computed)
+    assert plan.computed == n * (n + 1) // 2
+    full = attention_plan(s, s, 64, causal=False, block_q=block,
+                          block_k=block)
+    assert full.computed == full.steps == steps
+
+
+def test_attention_plan_skips_blocks_outside_the_window():
+    # rows of q block i see columns (64 i - 96, 64 i + 63]: the diagonal
+    # block and the one or two before it
+    plan = attention_plan(256, 256, 64, causal=True, window=96,
+                          block_q=64, block_k=64)
+    assert (plan.steps, plan.computed) == (16, 1 + 2 + 3 + 3)
+    # a q block past the first of the keys (chunked prefill) sees every
+    # block up to its own
+    plan = attention_plan(256, 512, 64, causal=True, q_offset=256,
+                          block_q=128, block_k=128)
+    assert (plan.steps, plan.computed) == (8, 3 + 4)
+
+
+def test_attention_plan_shrinks_blocks_to_its_vmem_budget():
+    plan = attention_plan(1024, 1024, 1024)
+    assert plan.block_q * plan.block_k < 512 * 512
+    assert _vmem_bytes(plan.block_q, plan.block_k, 1024, 2) <= VMEM_BUDGET
 
 
 @given(st.integers(1, 3), st.integers(1, 4), st.integers(0, 1),

@@ -23,6 +23,9 @@ from repro.models import transformer
 # new tokens; 9 query heads over 3 kv heads of width 64
 BATCH, PROMPT, DECODE = 8, 512, 128
 HEADS, KV_HEADS, HEAD_DIM = 9, 3, 64
+SMOLLM = (HEADS, KV_HEADS, HEAD_DIM)
+#: olmo-1b's attention widths: 16 heads of 128, as many kv heads
+OLMO = (16, 16, 128)
 
 
 @pytest.fixture(scope="module")
@@ -83,11 +86,43 @@ def test_flash_decode_sliced_compiles_at_batch_8(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_flash_attention_compiles_at_prompt_512(one_chip):
-    q = jax.ShapeDtypeStruct((BATCH, PROMPT, HEADS, HEAD_DIM), jnp.bfloat16)
-    kv = jax.ShapeDtypeStruct((BATCH, PROMPT, KV_HEADS, HEAD_DIM),
+def _prompt_shapes(one_chip, prompt, widths=SMOLLM):
+    heads, kv_heads, head_dim = widths
+    q = jax.ShapeDtypeStruct((BATCH, prompt, heads, head_dim), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((BATCH, prompt, kv_heads, head_dim),
                               jnp.bfloat16)
-    compiled = _compile(flash_attention, *_on(one_chip, (q, kv, kv)))
+    return _on(one_chip, (q, kv, kv))
+
+
+def _loss_and_grads(q, k, v):
+    return jax.value_and_grad(
+        lambda *a: flash_attention(*a).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))(q, k, v)
+
+
+# the three RT prefills of the benchmark's mix, and olmo-1b's head width:
+# the blocks these shapes take from attention_plan have to fit the chip's
+# VMEM
+@pytest.mark.parametrize("prompt,widths", [
+    *(pytest.param(p, SMOLLM, id=str(p)) for p in (128, PROMPT, 1024)),
+    *(pytest.param(p, OLMO, id=f"d128-{p}") for p in (PROMPT, 1024))])
+def test_flash_attention_compiles_at_prompt_512(one_chip, prompt, widths):
+    compiled = _compile(flash_attention,
+                        *_prompt_shapes(one_chip, prompt, widths))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_attention_and_its_vjp_compile_as_trained(one_chip):
+    """The BE train step runs the forward kernel and its VJP at 8 x 512."""
+    compiled = _compile(_loss_and_grads, *_prompt_shapes(one_chip, PROMPT))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("prompt", [PROMPT, 1024])
+def test_flash_attention_and_its_vjp_compile_at_head_dim_128(one_chip,
+                                                             prompt):
+    compiled = _compile(_loss_and_grads,
+                        *_prompt_shapes(one_chip, prompt, OLMO))
     assert "tpu_custom_call" in compiled.as_text()
 
 
