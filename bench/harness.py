@@ -70,6 +70,9 @@ class Run:
     sizes: dict
     mix: dict
     peak: dict
+    #: the BE job's configuration: the cell's, unless the mix's ``be``
+    #: names another under ``config``; None without a BE job
+    be_sizes: dict | None = None
     #: one dict per timed slice: kind (prefill|decode|train), task,
     #: start, end (monotonic s), and for decode the first position and
     #: the tokens it emitted
@@ -134,7 +137,7 @@ class Cell:
             self.sizes, seed, model.PROMPTS,
             {t["name"]: (pool, t["batch"], t["prompt"])
              for t in self.mix["rt"]}, device)
-        self.train = None
+        self.train = self.be_sizes = None
         self.slices: list = []
         self.current: dict = {}      # task -> the release in flight
         self.outputs: dict = {}      # (task, k) -> (first (B,), tokens (B,n))
@@ -146,8 +149,12 @@ class Cell:
         be = self.mix.get("be")
         if be is None:
             return
+        # the BE job trains its own configuration: the cell's, or the one
+        # the mix names; its weights and rows come from the same seed
+        self.be_sizes = catalog.config(be.get("config", c["config"]),
+                                       bench_dir)
         feed = model.make_tokens(
-            self.sizes, seed, model.FEED,
+            self.be_sizes, seed, model.FEED,
             {"rows": (be["feed_pool"], be["batch"], be["seq"] + 1)},
             device)["rows"]
         self.feed = [{"inputs": feed[i, :, :-1], "labels": feed[i, :, 1:]}
@@ -155,9 +162,10 @@ class Cell:
         self.opt = dict(be["optimizer"])
         opt_cfg = adamw.AdamWConfig(**{k: v for k, v in self.opt.items()
                                        if k != "z_loss"})
-        self._train_step = jax.jit(build_train_step(self.cfg, opt_cfg),
-                                   donate_argnums=(0, 1))
-        tp = model.make_params(self.sizes, seed, device)
+        self._train_step = jax.jit(
+            build_train_step(model.program_config(self.be_sizes), opt_cfg),
+            donate_argnums=(0, 1))
+        tp = model.make_params(self.be_sizes, seed, device)
         self.train = {"params": tp,
                       "opt": jax.jit(adamw.init_opt_state)(tp),
                       "steps": 0, "loss": None}
@@ -244,7 +252,7 @@ class Cell:
             if i == 0:
                 first = reference.leaf_norms(jax.tree.map(
                     lambda m: m / (1 - b1), self.train["opt"]["m"]))
-        p0 = model.make_params(self.sizes, self.seed, self.device)
+        p0 = model.make_params(self.be_sizes, self.seed, self.device)
         change = reference.change_norms(self.train["params"], p0)
         del p0
         return {"losses": losses, "grad": first, "change": change}
@@ -438,8 +446,9 @@ def train_readings(prog: dict, ref: dict) -> dict:
 
 def reference_train(sz: dict, mix: dict, seed: int, device, steps: int,
                     quant=None, half: bool = False) -> dict:
-    """The reference's three steps from the seed on the same rows; with
-    ``half`` the fault of a step that drops half of each batch."""
+    """The reference's three steps from the seed on the same rows, for the
+    BE job's configuration ``sz``; with ``half`` the fault of a step that
+    drops half of each batch."""
     be = mix["be"]
     feed = model.make_tokens(sz, seed, model.FEED,
                              {"rows": (be["feed_pool"], be["batch"],
@@ -614,7 +623,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     peak = peaks.get(dev.device_kind)
 
     cell = Cell(spec, seed, dev, bench_dir)
-    mix, sz = cell.mix, cell.sizes
+    mix, sz, be_sz = cell.mix, cell.sizes, cell.be_sizes
     n_check = mix["be"]["checked_steps"] if "be" in mix else 0
     prog_train = cell.first_steps(n_check) if n_check else None
     rt, be = _profiles(cell)
@@ -639,7 +648,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     picked = traffic.sample(mix, finished, seed)
     served = {(n, k): tuple(np.asarray(x) for x in cell.outputs[(n, k)])
               for n, ks in picked.items() for k in ks}
-    r = Run(cell_name, sz, mix, peak or {}, slices=list(cell.slices),
+    r = Run(cell_name, sz, mix, peak or {}, be_sz, slices=list(cell.slices),
             releases=ev["releases"],
             job_stats={n: j.stats for n, j in ev["jobs"].items()},
             timing=tim)
@@ -658,7 +667,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
         gtee["be_stalled"] = int(steps == 0)
 
     readings = serve_readings(sz, mix, seed, dev, served, controls)
-    ref_train = (reference_train(sz, mix, seed, dev, n_check)
+    ref_train = (reference_train(be_sz, mix, seed, dev, n_check)
                  if n_check else None)
     if n_check:
         readings.update(train_readings(prog_train, ref_train))
@@ -676,7 +685,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
         for tag, kw in (("half_batch", {"half": True}),
                         ("fp8", {"quant": "fp8"})):
             got = train_readings(
-                reference_train(sz, mix, seed, dev, n_check, **kw),
+                reference_train(be_sz, mix, seed, dev, n_check, **kw),
                 ref_train)
             ctl[tag].update(got)
             readings.update({f"{tag}.{k}": v for k, v in got.items()})

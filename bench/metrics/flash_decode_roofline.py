@@ -2,8 +2,9 @@
 time, from its shapes (each row's K and V up to its length), over the
 summed device time of its calls, in %.  Read from the trace: every
 decode slice whose span lies in it, with the flash-decode calls that ran
-inside the span, one per layer and token; a slice whose call count
-differs from that is left out, and with none left it gives nothing."""
+inside the span, one per attention layer and token (the count the
+configuration's family gives); a slice whose call count differs from
+that is left out, and with none left it gives nothing."""
 import bisect
 import re
 
@@ -24,7 +25,7 @@ def read(run):
                    if match.setdefault(n, bool(OP.search(n))))
     starts = [c[0] for c in calls]
     tasks = {t["name"]: t for t in run.mix["rt"]}
-    layers = run.sizes["num_hidden_layers"]
+    layers = flops.decode_attention_layers(run.sizes)
     least = took = 0.0
     for name, s, d, args in run.trace_events["host"]:
         if name != "bench.rt_decode_slice":

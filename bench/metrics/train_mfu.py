@@ -1,6 +1,6 @@
 """Train step (launch/steps.py): the model operations of the BE steps
-run (6·N·tokens plus the attention term) over their slices' time, as a
-share of the chip's peak bf16 rate, in %."""
+run (6·N·tokens plus the attention term, of the BE job's configuration)
+over their slices' time, as a share of the chip's peak bf16 rate, in %."""
 from bench import flops
 
 
@@ -12,5 +12,5 @@ def read(run):
     took = sum(x["end"] - x["start"] for x in s)
     if not took:
         return None
-    ops = len(s) * flops.train_step_flops(run.sizes, be["batch"], be["seq"])
+    ops = len(s) * flops.train_step_flops(run.be_sizes, be["batch"], be["seq"])
     return 100.0 * ops / took / run.peak["bf16_flops_per_s"]

@@ -1,11 +1,14 @@
-"""The plain reference: a decoder-only transformer (pre-norm, RoPE,
-grouped-query causal attention, SwiGLU MLP) in float32 jax.numpy at
-``highest`` matmul precision, its language-model loss and AdamW, written
-from the configuration's sizes.  It imports nothing of the program.
+"""The plain reference's shared pieces, in float32 jax.numpy at
+``highest`` matmul precision: matmuls (and the fp8 control's rounding),
+norms, RoPE, grouped-query causal attention, the SwiGLU MLP, the logits
+and language-model loss of a family's trunk, and AdamW.  Each family
+(``families/<family>.py``) builds its model from these; ``last_logits``
+and ``lm_loss`` here call the configuration's family.  It imports nothing
+of the program.
 
-It reads weights in the layout ``bench/model.py`` makes them.  ``quant``
-= "fp8" computes every matmul on float8 (e4m3) operands, each scaled by
-its largest magnitude: the control, one precision below the
+It reads weights in the layout the family's ``init`` makes them.
+``quant`` = "fp8" computes every matmul on float8 (e4m3) operands, each
+scaled by its largest magnitude: the control, one precision below the
 configuration's bfloat16."""
 from __future__ import annotations
 
@@ -13,6 +16,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from . import catalog
 
 HI = jax.lax.Precision.HIGHEST
 F32 = jnp.float32
@@ -55,68 +60,90 @@ def _rope(x, pos, theta):
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
 
 
-def _layer(sz, quant, x, p):
-    b, s, d = x.shape
+def attention(sz, quant, y, c):
+    """Grouped-query causal self-attention of the normed ``y`` (b, s, d)
+    through the weights ``c`` (wq, wk, wv, wo), its output projected
+    back to d; RoPE at the configuration's ``rope_theta``, none where it
+    states none."""
+    b, s, d = y.shape
     h, kv = sz["num_attention_heads"], sz["num_key_value_heads"]
     hd = sz.get("head_dim") or d // h
-    c, f = p["core"], p["ffn"]
-    y = _norm(sz, x, c.get("norm_w"))
     q = _mm(y, c["wq"], quant).reshape(b, s, h, hd)
     k = _mm(y, c["wk"], quant).reshape(b, s, kv, hd)
     v = _mm(y, c["wv"], quant).reshape(b, s, kv, hd)
     pos = jnp.arange(s)
-    q, k = _rope(q, pos, sz["rope_theta"]), _rope(k, pos, sz["rope_theta"])
+    if sz.get("rope_theta"):
+        q = _rope(q, pos, sz["rope_theta"])
+        k = _rope(k, pos, sz["rope_theta"])
     q = q.reshape(b, s, kv, h // kv, hd)
     scores = _mm(q, k, quant, "bqkgd,bskd->bkgqs") * hd ** -0.5
     causal = pos[:, None] >= pos[None, :]
     scores = jnp.where(causal, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     o = _mm(probs, v, quant, "bkgqs,bskd->bqkgd").reshape(b, s, h * hd)
-    x = x + _mm(o, c["wo"], quant)
-    y = _norm(sz, x, f.get("norm_w"))
+    return _mm(o, c["wo"], quant)
+
+
+def swiglu(quant, y, f):
+    """The SwiGLU MLP of the normed ``y`` through ``f`` (wi, wg, wo)."""
     up = _mm(y, f["wi"], quant) * jax.nn.silu(_mm(y, f["wg"], quant))
-    return x + _mm(up, f["wo"], quant)
+    return _mm(up, f["wo"], quant)
 
 
-def _trunk(sz, quant, params, tokens):
-    x = params["embed"][tokens].astype(F32)
-    layer = jax.checkpoint(functools.partial(_layer, sz, quant))
-    x, _ = jax.lax.scan(lambda x, p: (layer(x, p), None), x,
-                        params["blocks"][0])
-    return _norm(sz, x, params.get("final_norm_w"))
-
-
-def _unembed(params):
+def unembed(params):
     return params["unembed"] if "unembed" in params else params["embed"].T
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 4))
-def _last_logits(sz_items, quant, params, tokens, n_last):
+def _items(sz: dict) -> tuple:
+    """The configuration's scalar entries, hashable for a jit's static
+    argument."""
+    return tuple(sorted((k, v) for k, v in sz.items()
+                        if not isinstance(v, (dict, list))))
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 5))
+def _last_logits(trunk, sz_items, quant, params, tokens, n_last):
     sz = dict(sz_items)
-    x = _trunk(sz, quant, params, tokens)[:, -n_last:]
-    return _mm(x, _unembed(params), quant)
+    x = trunk(sz, quant, params, tokens)[:, -n_last:]
+    return _mm(x, unembed(params), quant)
 
 
-def last_logits(sz: dict, params, tokens, n_last: int, quant=None,
-                rows: int = 2):
+def trunk_last_logits(trunk, sz: dict, params, tokens, n_last: int,
+                      quant=None, rows: int = 2):
     """Logits (b, n_last, vocab) of the last ``n_last`` positions of each
     row of ``tokens`` (b, s), ``rows`` rows at a time so that a long
-    sequence fits."""
-    items = tuple(sorted((k, v) for k, v in sz.items()
-                         if not isinstance(v, (dict, list))))
-    out = [_last_logits(items, quant, params, tokens[i:i + rows], n_last)
+    sequence fits.  ``trunk(sz, quant, params, tokens)`` gives the final
+    normed hidden states; the unembedding (or the tied embedding)
+    follows."""
+    out = [_last_logits(trunk, _items(sz), quant, params,
+                        tokens[i:i + rows], n_last)
            for i in range(0, tokens.shape[0], rows)]
     return jnp.concatenate(out)
 
 
-def lm_loss(sz: dict, params, inputs, labels, z_loss: float, quant=None):
+def trunk_lm_loss(trunk, sz: dict, params, inputs, labels, z_loss: float,
+                  quant=None):
     """Mean next-token cross-entropy plus ``z_loss`` times the squared
-    log-partition, over every position."""
-    x = _trunk(sz, quant, params, inputs)
-    logits = _mm(x, _unembed(params), quant)
+    log-partition, over every position, of ``trunk``'s logits."""
+    x = trunk(sz, quant, params, inputs)
+    logits = _mm(x, unembed(params), quant)
     logz = jax.nn.logsumexp(logits, -1)
     gold = jnp.take_along_axis(logits, labels[..., None], -1)[..., 0]
     return jnp.mean(logz - gold + z_loss * logz ** 2)
+
+
+def last_logits(sz: dict, params, tokens, n_last: int, quant=None,
+                rows: int = 2):
+    """The configuration's family's logits of the last ``n_last``
+    positions of each row of ``tokens``."""
+    return catalog.family(sz).last_logits(sz, params, tokens, n_last,
+                                          quant, rows)
+
+
+def lm_loss(sz: dict, params, inputs, labels, z_loss: float, quant=None):
+    """The configuration's family's language-model loss."""
+    return catalog.family(sz).lm_loss(sz, params, inputs, labels, z_loss,
+                                      quant)
 
 
 def lr_at(opt: dict, step):
@@ -169,8 +196,7 @@ def train(sz: dict, opt: dict, params, batches, quant=None):
     their own type after each step.  Weight decay applies to every leaf
     of two or more dimensions, as the configuration's optimizer states
     it (a layer-stacked norm gain is such a leaf)."""
-    items = tuple(sorted((k, v) for k, v in sz.items()
-                         if not isinstance(v, (dict, list))))
+    items = _items(sz)
     opt_items = tuple(sorted(opt.items()))
     m = jax.tree.map(lambda t: jnp.zeros(t.shape, F32), params)
     v = jax.tree.map(lambda t: jnp.zeros(t.shape, F32), params)

@@ -3,13 +3,18 @@
   JAX_PLATFORMS=cpu PYTHONPATH=src python -m pytest -q bench/tests
 
 The trace reduction on a small trace recorded on a v5e, the operation
-and byte counts against numbers worked out by hand, the response-from-
-due arithmetic, discovery of new data files by name, the refusals of
-``bench/run.py``, and whole runs of a tiny cell with the timed path
-broken underneath, each of which has to come out not correct."""
+and byte counts against numbers worked out by hand, the dense family's
+weights, reference and counts against values recorded before it moved
+into ``families/dense.py``, the response-from-due arithmetic, discovery
+of new data files by name, the refusals of ``bench/run.py``, and whole
+runs of a tiny cell with the timed path broken underneath, each of which
+has to come out not correct, and of a cell whose family and BE
+configuration come as new files."""
 from __future__ import annotations
 
+import hashlib
 import json
+import math
 import os
 import shutil
 import subprocess
@@ -25,6 +30,11 @@ from bench import catalog, flops, traffic  # noqa: E402
 from bench import trace as tr  # noqa: E402
 
 SMOLLM = catalog.config("smollm-135m")
+#: smollm-135m cut to 2 layers of width 72 with a 128-token vocabulary
+TINY = dict(SMOLLM, num_hidden_layers=2, hidden_size=72, head_dim=8,
+            intermediate_size=192, vocab_size=128)
+TINY_REDUCED = ["num_hidden_layers", "hidden_size", "head_dim",
+                "intermediate_size", "vocab_size", "rms_norm_eps"]
 #: OLMo-1B's widths (arXiv:2402.00838) at 8 of its 16 layers
 OLMO = {"num_hidden_layers": 8, "hidden_size": 2048,
         "num_attention_heads": 16, "num_key_value_heads": 16,
@@ -114,6 +124,100 @@ def test_prefill_and_train_by_hand():
     t = flops.least_time_s(flops.decode_step_flops(SMOLLM, 8, 641),
                            flops.decode_step_bytes(SMOLLM, 8, 641), peak)
     assert t == flops.decode_step_bytes(SMOLLM, 8, 641) / 819e9
+
+
+# -- the dense family, pinned -------------------------------------------------
+
+#: recorded from the dense code as it stood before it moved into
+#: ``families/dense.py``, on the CPU: for smollm-135m and TINY, every
+#: count the readers use and, at each seed, each weight leaf's float64 sum
+#: and CRC-32, the reference's last logits on a seeded prompt (sum and
+#: CRC-32), and three AdamW steps' losses, first-gradient norms and
+#: update norms
+GOLDEN = json.loads((CHECKOUT / "bench" / "testdata" /
+                     "dense_golden.json").read_text())
+GOLDEN_CONFIGS = {"smollm-135m": SMOLLM, "tiny": TINY}
+GOLDEN_SHAPES = {"prefill": [(2, 16), (8, 128), (8, 512), (8, 1024)],
+                 "ctx": [(2, 17), (8, 129), (8, 641), (8, 1056)],
+                 "train": [(4, 32), (8, 512)]}
+
+
+def _dense_counts(sz):
+    out = {}
+    for b, s in GOLDEN_SHAPES["prefill"]:
+        out[f"prefill_flops.{b}.{s}"] = flops.prefill_flops(sz, b, s)
+        out[f"prefill_bytes.{b}.{s}"] = flops.prefill_bytes(sz, b, s)
+    for b, c in GOLDEN_SHAPES["ctx"]:
+        out[f"decode_step_flops.{b}.{c}"] = flops.decode_step_flops(sz, b, c)
+        out[f"decode_step_bytes.{b}.{c}"] = flops.decode_step_bytes(sz, b, c)
+        out[f"flash_decode_call.{b}.{c}"] = list(
+            flops.flash_decode_call(sz, b, c))
+    for b, s in GOLDEN_SHAPES["train"]:
+        out[f"train_step_flops.{b}.{s}"] = flops.train_step_flops(sz, b, s)
+    return out
+
+
+def _dense_readings(sz, seed):
+    import zlib
+
+    import jax
+    import numpy as np
+
+    from bench import harness, model, reference
+    params = model.make_params(sz, seed)
+    weights = {}
+    for path, x in jax.tree_util.tree_flatten_with_path(params)[0]:
+        a = np.asarray(x)
+        weights[jax.tree_util.keystr(path)] = [
+            float(a.astype(np.float64).sum()), zlib.crc32(a.tobytes())]
+    prompt = model.make_tokens(sz, seed, model.PROMPTS, {"p": (2, 12)})["p"]
+    lg = np.asarray(reference.last_logits(sz, params, prompt, 3))
+    opt = catalog.traffic("rt3-over-train.smollm-135m")["be"]["optimizer"]
+    mix = {"be": {"batch": 2, "seq": 16, "feed_pool": 3, "optimizer": opt}}
+    tr_ = harness.reference_train(sz, mix, seed, None, 3)
+    return {"weights": weights,
+            "logits": [float(lg.astype(np.float64).sum()),
+                       zlib.crc32(lg.tobytes())],
+            "losses": tr_["losses"], "grad": tr_["grad"],
+            "change": tr_["change"]}
+
+
+@pytest.mark.parametrize("name", sorted(GOLDEN_CONFIGS))
+def test_dense_counts_are_pinned(name):
+    sz = GOLDEN_CONFIGS[name]
+    assert _dense_counts(sz) == GOLDEN[name]["counts"]
+    assert flops.decode_attention_layers(sz) == sz["num_hidden_layers"]
+
+
+@pytest.mark.parametrize("seed", [5, 2 ** 33 + 12345])
+@pytest.mark.parametrize("name", sorted(GOLDEN_CONFIGS))
+def test_dense_weights_and_reference_are_pinned(name, seed):
+    """Bit for bit: the seed's weights, the reference's logits, and three
+    reference AdamW steps through ``harness.reference_train``."""
+    assert _dense_readings(GOLDEN_CONFIGS[name], seed) == \
+        GOLDEN[name]["seeds"][str(seed)]
+
+
+def test_attention_without_rope_sees_no_positions():
+    """Without ``rope_theta`` the shared reference attention has no
+    positional encoding: the last position's output is the same whatever
+    the order of the positions before it.  With RoPE it is not."""
+    import jax
+    import jax.numpy as jnp
+
+    from bench import reference
+    sz = {"num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 8}
+    k = jax.random.split(jax.random.PRNGKey(0), 5)
+    shapes = {"wq": (16, 32), "wk": (16, 16), "wv": (16, 16), "wo": (32, 16)}
+    c = {n: jax.random.normal(kk, s) for (n, s), kk in zip(shapes.items(), k)}
+    y = jax.random.normal(k[4], (1, 6, 16))
+    moved = y[:, jnp.array([2, 0, 4, 1, 3, 5])]
+
+    def last(sz, y):
+        return reference.attention(sz, None, y, c)[:, -1]
+    assert jnp.allclose(last(sz, y), last(sz, moved), atol=1e-5)
+    roped = dict(sz, rope_theta=10000.0)
+    assert not jnp.allclose(last(roped, y), last(roped, moved), atol=1e-3)
 
 
 # -- response from the due time ----------------------------------------------
@@ -294,10 +398,7 @@ def tiny(tmp_path_factory):
     shutil.copytree(CHECKOUT / "bench", root / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
     b = root / "bench"
-    cfg = dict(SMOLLM, num_hidden_layers=2, hidden_size=72, head_dim=8,
-               intermediate_size=192, vocab_size=128)
-    cfg["reduced"] = ["num_hidden_layers", "hidden_size", "head_dim",
-                      "intermediate_size", "vocab_size", "rms_norm_eps"]
+    cfg = dict(TINY, reduced=TINY_REDUCED)
     (b / "configs" / "tiny.json").write_text(json.dumps(cfg))
     mix = catalog.traffic("rt3-over-train.smollm-135m")
     mix["rt"] = [dict(name="hi", batch=2, prompt=16, new_tokens=8,
@@ -319,15 +420,15 @@ def tiny(tmp_path_factory):
     return root
 
 
-def _tiny_run(root, monkeypatch, seed=5, controls=False):
+def _tiny_run(root, monkeypatch, seed=5, controls=False, cell="tiny.mix",
+              log=lambda *a: None):
     from bench import harness
     from repro.kernels import ops
     monkeypatch.setenv("REPRO_PALLAS", "interpret")
     ops.set_use_pallas(True, interpret=True)
     try:
-        return harness.run("tiny.mix", seed, 1.5, False, checkout=root,
-                           need_tpu=False, controls=controls,
-                           log=lambda *a: None)
+        return harness.run(cell, seed, 1.5, False, checkout=root,
+                           need_tpu=False, controls=controls, log=log)
     finally:
         ops.set_use_pallas(None)
 
@@ -363,7 +464,8 @@ def test_control_reads_above_the_program(tiny, monkeypatch):
     assert list(out)[-1] == "checks"
 
 
-def test_state_left_unchanged_is_caught(tiny, monkeypatch):
+def _freeze_train_state(monkeypatch):
+    """The fault of a train step that returns its state unchanged."""
     import repro.launch.steps as steps
     real = steps.build_train_step
 
@@ -375,6 +477,10 @@ def test_state_left_unchanged_is_caught(tiny, monkeypatch):
             return params, opt, m
         return f
     monkeypatch.setattr(steps, "build_train_step", frozen)
+
+
+def test_state_left_unchanged_is_caught(tiny, monkeypatch):
+    _freeze_train_state(monkeypatch)
     out = _tiny_run(tiny, monkeypatch)
     assert not out["correct"]
     assert {"train_grad_gap", "train_update_gap"} <= _failed(out)
@@ -443,3 +549,154 @@ def test_a_mix_without_training_runs(tiny, monkeypatch):
     assert out["correct"], out["checks"]
     assert "be_tokens_per_s" not in out["metrics"]
     assert "train_grad_gap" not in out["checks"]
+
+
+# -- a family and a BE configuration brought as new files --------------------
+
+#: a second family, as a later configuration brings one: dense layers in a
+#: pattern of attention positions, each stacked over its share of the
+#: layers, and an untied unembedding; its counts are dense's
+PAIRED_FAMILY = '''\
+"""Dense layers in a pattern of attention positions, each stacked over
+its share of the layers, and an untied unembedding; counted as dense."""
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from bench import catalog, reference
+
+dense = catalog.family({"family": "dense"})
+FIELDS = dense.FIELDS
+
+
+def __getattr__(name):
+    return getattr(dense, name)
+
+
+def program_fields(sz):
+    from repro.models.blocks import BlockSpec
+    return {"layer_pattern": ("pattern", tuple(
+        BlockSpec(k) for k in sz["layer_pattern"]))}
+
+
+def init(sz, k):
+    n = len(sz["layer_pattern"])
+    ks = jax.random.split(k, n + 1)
+    per = dict(sz, num_hidden_layers=sz["num_hidden_layers"] // n,
+               tie_word_embeddings=True)
+    parts = [dense.init(per, kk) for kk in ks[:n]]
+    d, v = sz["hidden_size"], sz["vocab_size"]
+    un = jax.random.normal(ks[n], (d, v), jnp.float32) * d ** -0.5
+    return dict(parts[0], blocks=[p["blocks"][0] for p in parts],
+                unembed=un.astype(jnp.bfloat16))
+
+
+def trunk(sz, quant, params, tokens):
+    x = params["embed"][tokens].astype(reference.F32)
+    one = jax.checkpoint(functools.partial(dense.layer, sz, quant))
+
+    def repeat(x, ps):
+        for p in ps:
+            x = one(x, p)
+        return x, None
+    x, _ = jax.lax.scan(repeat, x, params["blocks"])
+    return reference._norm(sz, x, params.get("final_norm_w"))
+
+
+def last_logits(sz, params, tokens, n_last, quant=None, rows=2):
+    return reference.trunk_last_logits(trunk, sz, params, tokens, n_last,
+                                       quant, rows)
+
+
+def lm_loss(sz, params, inputs, labels, z_loss, quant=None):
+    return reference.trunk_lm_loss(trunk, sz, params, inputs, labels,
+                                   z_loss, quant)
+'''
+
+#: the BE job's own configuration is smollm-135m's layout at other sizes,
+#: with this vocabulary
+BE_VOCAB = 160
+#: limits for the paired cell, set from its readings on the CPU over seeds
+#: 7-12: sound runs read 0-0.028, 1.8e-5-3.2e-5, 0.0013-0.0025 and
+#: 0.0088-0.0164; the fp8 reference 0.070-0.70 (logit gap) and a step on
+#: half its batch 1.4e-3-4.4e-3, 0.066-0.20 and 0.073-0.15; a state left
+#: unchanged reads 1 on both norm gaps
+PAIRED_LIMITS = {"serve_gap": 0.05, "train_loss_gap": 3e-4,
+                 "train_grad_gap": 0.008, "train_update_gap": 0.04}
+
+
+def _tree(d: Path) -> dict:
+    return {str(p.relative_to(d)): hashlib.sha256(p.read_bytes()).hexdigest()
+            for p in sorted(d.rglob("*"))
+            if p.is_file() and "__pycache__" not in p.parts}
+
+
+@pytest.fixture(scope="module")
+def paired(tmp_path_factory):
+    """A checkout with one cell whose family, served configuration, BE
+    configuration, mix and limits are new files beside an unchanged copy
+    of the benchmark; returns (root, the copy's files before, the new
+    files)."""
+    root = tmp_path_factory.mktemp("paired")
+    shutil.copytree(CHECKOUT / "bench", root / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    b = root / "bench"
+    before = _tree(b)
+    served = dict(TINY, family="paired", num_hidden_layers=4,
+                  layer_pattern=["attn", "attn"], tie_word_embeddings=False,
+                  reduced=TINY_REDUCED + ["layer_pattern",
+                                          "tie_word_embeddings"])
+    trained = dict(SMOLLM, num_hidden_layers=2, hidden_size=48,
+                   num_attention_heads=6, num_key_value_heads=2, head_dim=8,
+                   intermediate_size=128, vocab_size=BE_VOCAB,
+                   reduced=TINY_REDUCED + ["num_attention_heads",
+                                           "num_key_value_heads"])
+    mix = catalog.traffic("rt3-over-train.smollm-135m")
+    mix["rt"] = [dict(name="hi", batch=2, prompt=16, new_tokens=8,
+                      slice_tokens=4, period_ms=600.0, priority=50,
+                      phase=0.1)]
+    mix["be"].update(batch=4, seq=32, config="tiny-be")
+    new = {"families/paired.py": PAIRED_FAMILY,
+           "configs/tiny-paired.json": json.dumps(served),
+           "configs/tiny-be.json": json.dumps(trained),
+           "traffic/over-tiny-be.json": json.dumps(mix),
+           "limits/tiny-paired.over-be.json": json.dumps(PAIRED_LIMITS)}
+    for rel, text in new.items():
+        (b / rel).write_text(text)
+    spec = catalog.benchmark()
+    spec["workloads"] = [{"name": "tiny-paired.over-be",
+                          "config": "tiny-paired", "traffic": "over-tiny-be",
+                          "chips": 1, "why": "test"}]
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        if "workloads" in m:
+            m["workloads"] = ["tiny-paired.over-be"]
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    return root, before, set(new)
+
+
+def test_new_family_and_be_config_need_only_new_files(paired, monkeypatch):
+    """The served model is the new family's, the BE job trains the
+    configuration its mix names, and the run comes out correct with no
+    file of the benchmark changed."""
+    root, before, new = paired
+    lines = []
+    out = _tiny_run(root, monkeypatch, seed=7, cell="tiny-paired.over-be",
+                    log=lines.append)
+    assert out["correct"], out["checks"]
+    assert out["attempted"] > 0 and "be_tokens_per_s" in out["metrics"]
+    after = _tree(root / "bench")
+    assert set(after) == set(before) | new
+    assert {k: v for k, v in after.items() if k not in new} == before
+    # the first losses are those of the BE configuration's vocabulary
+    losses = json.loads(lines[-1])["info"]["train_losses"]
+    for x in losses["program"][:1] + losses["reference"][:1]:
+        assert abs(x - math.log(BE_VOCAB)) < 0.1, losses
+
+
+def test_new_be_config_state_left_unchanged_is_caught(paired, monkeypatch):
+    _freeze_train_state(monkeypatch)
+    out = _tiny_run(paired[0], monkeypatch, seed=7,
+                    cell="tiny-paired.over-be")
+    assert not out["correct"]
+    assert {"train_grad_gap", "train_update_gap"} <= _failed(out)
